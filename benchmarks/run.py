@@ -29,6 +29,8 @@ import argparse
 import sys
 import time
 
+from repro.launch.cache import enable_compile_cache
+
 MODULES = ("selectors", "sweep", "async", "overhead", "estimation",
            "ablations", "kernels", "roofline")
 
@@ -43,6 +45,7 @@ def main():
     ap.add_argument("--only", default="",
                     help="comma-separated subset of: " + ",".join(MODULES))
     args = ap.parse_args()
+    enable_compile_cache()
     only = set(filter(None, args.only.split(",")))
     todo = [m for m in MODULES if not only or m in only]
     t_all = time.time()

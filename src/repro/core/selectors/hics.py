@@ -17,7 +17,7 @@ Two distance paths feed the clustering:
   (``repro.kernels.hics_selection_step_cached``): O(K·N·C) per round.
 * ``incremental=False`` — the from-scratch fused device step
   (``repro.kernels.hics_selection_step``): one pre-Gram HBM sweep over
-  (N, C) into the MXU-tiled Gram/arccos kernel, O(N²·C) per round.
+  (N, C) into the MXU-tiled Gram kernel, O(N²·C) per round.
   Kept as the parity oracle (tests/test_incremental_selection.py locks
   the two paths together) and for drivers that mutate Δb out-of-band.
 

@@ -5,13 +5,15 @@
   gram_update      — K-row incremental refresh of a cached distance
                      matrix (Alg. 1 replaces K rows per round, so the
                      strip is O(K·N·C) vs the full step's O(N²·C)),
-                     with a pluggable epilogue: arccos+λ|ΔĤ| (Eq. 9,
-                     HiCS), cosine (Clustered Sampling) or L2 (DivFL)
+                     ending in one of three distances: arccos+λ|ΔĤ|
+                     (Eq. 9, HiCS), cosine (Clustered Sampling) or L2
+                     (DivFL)
   hetero_entropy   — fused temperature-softmax entropy over class blocks
                      (entropy-only API; fused_stats supersedes it on the
                      selection path)
-  pairwise         — Eq. 9 distance: MXU-tiled Gram + arccos/λ|ΔĤ|
-                     epilogue, plus the end-to-end fused selection step
+  pairwise         — Eq. 9 distance: MXU-tiled Gram/cosine kernel + the
+                     arccos/λ|ΔĤ| tail in XLA, plus the end-to-end
+                     fused selection step
   decode_attention — GQA flash-decode for the serving hot loop
 
 Each kernel has a pure-jnp oracle in ref.py; ops.py is the dispatching

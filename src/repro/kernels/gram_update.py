@@ -12,23 +12,24 @@ for the refreshed rows u ∈ ids, O(K·N·C), and scatters it back into
 the cached matrix (rows AND columns — dot products are symmetric, so
 the scatter keeps the cache exactly symmetric).
 
-The Gram product is metric-agnostic, so the Eq. 9 arccos+λ|ΔĤ| tail is
-one of three pluggable EPILOGUES applied on the last C block: "arccos"
-(HiCS), "cosine" (Clustered Sampling's angular distance over full
-updates) and "l2" (DivFL's Euclidean distance, rebuilt from the cached
-norms via |a−b|² = |a|² + |b|² − 2⟨a, b⟩).  That one switch lets the
-full-update baselines ride the SAME cached K-row path HiCS uses —
-``cached_feature_step_pallas`` below — which is what puts DivFL/CS on
-the scanned round loop at O(K·N·F) per round.
+The Gram product is metric-agnostic, so the distance is one of three
+EPILOGUES: "arccos" (Eq. 9, HiCS), "cosine" (Clustered Sampling's
+angular distance over full updates) and "l2" (DivFL's Euclidean
+distance, rebuilt from the cached norms via |a−b|² = |a|² + |b|² −
+2⟨a, b⟩).  That one switch lets the full-update baselines ride the SAME
+cached K-row path HiCS uses — ``cached_feature_step_pallas`` below —
+which is what puts DivFL/CS on the scanned round loop at O(K·N·F) per
+round.
 
 The strip kernel reuses the Gram tiling of ``kernels/pairwise``: (BK,
 BC) × (BN, BC) partial products accumulated in a VMEM f32 scratch over
-the sequential C axis, with the normalize→clip→arccos→+λ|ΔĤ| epilogue
-applied on the last C block so the strip is written to HBM exactly
-once.  ``gram_in_bf16`` casts both Gram operands to bf16 (f32
-accumulation stays) for 2× operand bandwidth, exactly like the full
-kernel.  The true diagonal is zeroed via the refreshed rows' GLOBAL
-indices, which ride along as a (K, 1) int32 operand.
+the sequential C axis.  On the last C block it writes the clipped
+cosine (or, for "l2", the distance) so the strip reaches HBM exactly
+once; the arccos, the true-diagonal zeroing (by the refreshed rows'
+GLOBAL ids) and +λ|ΔĤ| run in XLA on the (K, N) strip
+(``pairwise.gram_tail``) — Mosaic cannot lower ``acos``.
+``gram_in_bf16`` casts both Gram operands to bf16 (f32 accumulation
+stays) for 2× operand bandwidth, exactly like the full kernel.
 
 ``cached_selection_step_pallas`` is the end-to-end incremental
 selection step: gather the K rows, one fused-stats sweep over (K, C)
@@ -47,110 +48,61 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
 from repro.kernels.fused_stats import _fused_stats_padded
-from repro.kernels.pairwise import _gram_blocks
+from repro.kernels.pairwise import _gram_blocks, _gram_tile, gram_tail
 
 
-#: strip-kernel epilogues: how the K×N Gram product becomes a distance.
+#: strip epilogues: how the K×N Gram product becomes a distance.
 #: "arccos" is Eq. 9 (HiCS); "cosine" is the angular distance alone
 #: (Clustered Sampling); "l2" is Euclidean distance from the cached
-#: norms (DivFL).  Static per trace — each picks a different tail of
-#: VPU arithmetic on the final C block.
+#: norms (DivFL).  The kernel stops at the clipped cosine (or the L2
+#: distance); the rest is ``pairwise.gram_tail`` in XLA.
 EPILOGUES = ("arccos", "cosine", "l2")
 
+_BK = 8   # strip row-tile: K is small (a cohort), one VPU sublane tile
 
-def _gram_row_kernel(rows_ref, x_ref, stats_r_ref, stats_c_ref, ids_ref,
-                     o_ref, acc_ref, *, lam, eps, block_n, epilogue):
+
+def _gram_row_kernel(rows_ref, x_ref, stats_r_ref, stats_c_ref, o_ref,
+                     acc_ref, *, eps, l2):
     ci = pl.program_id(2)
     nc = pl.num_programs(2)
-    j = pl.program_id(1)
 
     @pl.when(ci == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = rows_ref[...].astype(jnp.float32)     # (BK, BC) refreshed rows
-    b = x_ref[...].astype(jnp.float32)        # (BN, BC) all-clients tile
-    acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    # (BK, BC) refreshed rows × (BN, BC) all-clients tile
+    acc_ref[...] += _gram_tile(rows_ref, x_ref)
 
     @pl.when(ci == nc - 1)
     def _epilogue():
         # stats lanes: [:, 0] = L2 norm, [:, 1] = entropy
         nr = stats_r_ref[..., 0:1].astype(jnp.float32)    # (BK, 1)
         ncol = stats_c_ref[..., 0:1].astype(jnp.float32)  # (BN, 1)
-        if epilogue == "l2":
+        if l2:
             # √(|a|² + |b|² − 2⟨a, b⟩) from the cached norms; the clip
             # absorbs the fp cancellation of near-identical rows
-            d = jnp.sqrt(jnp.clip(
+            o_ref[...] = jnp.sqrt(jnp.clip(
                 nr * nr + (ncol * ncol).T - 2.0 * acc_ref[...], 0.0,
                 None))
         else:                                 # cosine family
             denom = jnp.maximum(nr, eps) * jnp.maximum(ncol, eps).T
-            cos = acc_ref[...] / denom
-            cos = jnp.clip(cos, -1.0 + 1e-7, 1.0 - 1e-7)
-            d = jnp.arccos(cos)
-        # zero the TRUE diagonal: the strip row's global client index
-        # (ids operand) against the tile's global column range
-        row_id = ids_ref[..., 0:1]                        # (BK, 1) int32
-        col = j * block_n + jax.lax.broadcasted_iota(jnp.int32, d.shape,
-                                                     1)
-        d = jnp.where(row_id == col, 0.0, d)
-        if epilogue == "arccos":
-            hr = stats_r_ref[..., 1:2].astype(jnp.float32)    # (BK, 1)
-            hc = stats_c_ref[..., 1:2].astype(jnp.float32)    # (BN, 1)
-            d = d + lam * jnp.abs(hr - hc.T)
-        o_ref[...] = d
+            o_ref[...] = jnp.clip(acc_ref[...] / denom, -1.0 + 1e-7,
+                                  1.0 - 1e-7)
 
 
-def _gram_rows_padded(rows: jnp.ndarray, x: jnp.ndarray,
-                      stats_rows: jnp.ndarray, stats_all: jnp.ndarray,
-                      row_ids: jnp.ndarray, lam: float, eps: float,
-                      bk: int, bn: int, block_c: int,
-                      interpret: bool,
-                      epilogue: str = "arccos") -> jnp.ndarray:
-    """Strip kernel on already padded buffers.
-
-    rows (k_pad, c_pad), x (n_pad, c_pad), stats (k_pad, 2)/(n_pad, 2)
-    with nonzero norms on padded entries, row_ids (k_pad, 1) int32 with
-    -1 on padded entries (never matches a live column).
-    """
+def _gram_strip(x_pad: jnp.ndarray, stats: jnp.ndarray, ids: jnp.ndarray,
+                n: int, lam: float, bn: int, block_c: int,
+                gram_in_bf16: bool, interpret: bool,
+                epilogue: str = "arccos") -> jnp.ndarray:
+    """(K, N) distance strip from the padded (n_pad, c_pad) buffer and
+    the CURRENT (N, 2) stats: the strip kernel, then ``gram_tail``.
+    Shared by every entry point so their invariants cannot drift:
+    padded stats lanes carry norm 1 (never divide by eps²) and the bf16
+    cast happens AFTER any f32 consumer of the buffers."""
     if epilogue not in EPILOGUES:
         raise ValueError(f"unknown epilogue {epilogue!r}; expected one "
                          f"of {EPILOGUES}")
-    k_pad, c_pad = rows.shape
-    n_pad = x.shape[0]
-    grid = (k_pad // bk, n_pad // bn, c_pad // block_c)
-    return pl.pallas_call(
-        functools.partial(_gram_row_kernel, lam=lam, eps=eps,
-                          block_n=bn, epilogue=epilogue),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bk, block_c), lambda i, j, k: (i, k)),  # rows
-            pl.BlockSpec((bn, block_c), lambda i, j, k: (j, k)),  # cols
-            pl.BlockSpec((bk, 2), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((bn, 2), lambda i, j, k: (j, 0)),
-            pl.BlockSpec((bk, 1), lambda i, j, k: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bk, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((k_pad, n_pad), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
-        interpret=interpret,
-    )(rows, x, stats_rows, stats_all, row_ids)
-
-
-_BK = 8   # strip row-tile: K is small (a cohort), one VPU sublane tile
-
-
-def _strip_operands(x_pad: jnp.ndarray, stats: jnp.ndarray,
-                    ids: jnp.ndarray, n: int, gram_in_bf16: bool):
-    """Padded/aligned operands for the strip kernel, shared by both
-    entry points so their invariants cannot drift: padded stats lanes
-    carry norm 1 (never divide by eps²), padded row ids carry -1
-    (never matches a live column), and the bf16 cast happens AFTER any
-    f32 consumer of the buffers.  Returns (rows, x, stats_rows,
-    stats_all, row_ids, k_pad)."""
-    n_pad = x_pad.shape[0]
+    n_pad, c_pad = x_pad.shape
     k = ids.shape[0]
     k_pad = max(_BK, -(-k // _BK) * _BK)
     rows = jnp.pad(x_pad[ids], ((0, k_pad - k), (0, 0)))
@@ -160,12 +112,26 @@ def _strip_operands(x_pad: jnp.ndarray, stats: jnp.ndarray,
          jnp.pad(stats[:, 1], (0, n_pad - n))], axis=-1)
     stats_rows = jnp.pad(stats[ids], ((0, k_pad - k), (0, 0)),
                          constant_values=1.0)
-    row_ids = jnp.pad(ids.astype(jnp.int32), (0, k_pad - k),
-                      constant_values=-1)[:, None]
     if gram_in_bf16:
         x_pad = x_pad.astype(jnp.bfloat16)
         rows = rows.astype(jnp.bfloat16)
-    return rows, x_pad, stats_rows, stats_all, row_ids, k_pad
+    g = pl.pallas_call(
+        functools.partial(_gram_row_kernel, eps=1e-8,
+                          l2=epilogue == "l2"),
+        grid=(k_pad // _BK, n_pad // bn, c_pad // block_c),
+        in_specs=[
+            pl.BlockSpec((_BK, block_c), lambda i, j, k: (i, k)),  # rows
+            pl.BlockSpec((bn, block_c), lambda i, j, k: (j, k)),   # cols
+            pl.BlockSpec((_BK, 2), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((bn, 2), lambda i, j, k: (j, 0)),
+        ],
+        out_specs=pl.BlockSpec((_BK, bn), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((k_pad, n_pad), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((_BK, bn), jnp.float32)],
+        interpret=interpret,
+    )(rows, x_pad, stats_rows, stats_all)
+    return gram_tail(g[:k, :n], ids, stats[ids, 1], stats[:, 1], lam,
+                     epilogue)
 
 
 @functools.partial(jax.jit,
@@ -188,16 +154,11 @@ def gram_row_update_pallas(updates: jnp.ndarray, stats: jnp.ndarray,
     the stats refresh and the cache scatter.
     """
     n, c = updates.shape
-    k = ids.shape[0]
     bn, n_pad, c_pad = _gram_blocks(n, c, block_n, block_c)
     x = jnp.pad(updates.astype(jnp.float32), ((0, n_pad - n),
                                               (0, c_pad - c)))
-    rows, x, stats_rows, stats_all, row_ids, _ = _strip_operands(
-        x, stats, ids, n, gram_in_bf16)
-    strip = _gram_rows_padded(rows, x, stats_rows, stats_all, row_ids,
-                              lam, 1e-8, _BK, bn, block_c, interpret,
-                              epilogue=epilogue)
-    return strip[:k, :n]
+    return _gram_strip(x, stats, ids, n, lam, bn, block_c, gram_in_bf16,
+                       interpret, epilogue)
 
 
 @functools.partial(jax.jit,
@@ -239,11 +200,8 @@ def cached_selection_step_pallas(updates: jnp.ndarray, dist: jnp.ndarray,
                                           block_c, interpret)
     stats = stats.at[ids].set(
         jnp.stack([norm_r[:k], ent_r[:k]], axis=-1))
-    rows, xg, stats_rows, stats_all, row_ids, _ = _strip_operands(
-        x, stats, ids, n, gram_in_bf16)
-    strip = _gram_rows_padded(rows, xg, stats_rows, stats_all, row_ids,
-                              lam, 1e-8, _BK, bn, block_c,
-                              interpret)[:k, :n]
+    strip = _gram_strip(x, stats, ids, n, lam, bn, block_c, gram_in_bf16,
+                        interpret)
     dist = dist.at[ids].set(strip)
     dist = dist.at[:, ids].set(strip.T)
     return stats[:, 1], dist, stats
@@ -284,11 +242,8 @@ def cached_feature_step_pallas(feats: jnp.ndarray, dist: jnp.ndarray,
     norms = jnp.sqrt(jnp.sum(rows_f32 * rows_f32, axis=-1))
     stats = stats.at[ids].set(
         jnp.stack([norms, jnp.zeros_like(norms)], axis=-1))
-    rows, xg, stats_rows, stats_all, row_ids, _ = _strip_operands(
-        x, stats, ids, n, gram_in_bf16)
-    strip = _gram_rows_padded(rows, xg, stats_rows, stats_all, row_ids,
-                              0.0, 1e-8, _BK, bn, block_c, interpret,
-                              epilogue=metric)[:k, :n]
+    strip = _gram_strip(x, stats, ids, n, 0.0, bn, block_c, gram_in_bf16,
+                        interpret, metric)
     # the oracle's scatter (transpose-averaged K×K block) keeps the
     # exact-symmetry invariant identical across backends
     return ref._scatter_strip_symmetric(dist, strip, ids), stats

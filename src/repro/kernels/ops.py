@@ -1,8 +1,11 @@
 """Public kernel API with backend dispatch.
 
-On TPU the Pallas kernels run compiled; on CPU (this container) they
-run under ``interpret=True`` or fall back to the jnp oracle — both
-paths are bit-for-bit validated against ``ref.py`` by the test suite.
+On a TPU the Pallas kernels run compiled (``python chip_smoke.py``
+checks each against its oracle there).  On the CPU, where the tests run
+with ``JAX_PLATFORMS=cpu``, the default is the jitted jnp oracle and
+``use_pallas=True`` runs the kernel body in interpret mode; both are
+validated against ``ref.py`` by the test suite, and
+``tests/test_tpu_compile.py`` compiles the kernels for a described v5e.
 
     estimate_entropies(updates, T)          (N, C) -> (N,)
     hics_selection_step(updates, T, lam)    (N, C) -> ((N,), (N, N))
@@ -71,7 +74,7 @@ def hics_selection_step(updates: jnp.ndarray, temperature: float,
         (N, C) Δb  ->  (Ĥ (N,), Eq. 9 distance (N, N))
 
     One pad, one pre-Gram sweep (fused entropy+norm+RMS), then the
-    Gram/arccos kernel with no host round trip.  ``normalize=True``
+    Gram kernel and the arccos tail with no host round trip.  ``normalize=True``
     uses the RMS-normalized estimator (one extra stats sweep on the
     kernel path).  Pallas on TPU, jitted oracle on CPU.
     """
@@ -198,7 +201,7 @@ def _cached_feature_step_ref_jit(feats, dist, stats, ids, metric):
 def pairwise_distances(updates: jnp.ndarray, temperature: float,
                        lam: float = 10.0,
                        use_pallas: bool | None = None) -> jnp.ndarray:
-    """Full Eq. 9 matrix: one fused stats sweep + Gram/arccos kernel."""
+    """Full Eq. 9 matrix: one fused stats sweep + Gram kernel."""
     use = _on_tpu() if use_pallas is None else use_pallas
     if use:
         _, dist = hics_selection_step_pallas(updates, temperature,

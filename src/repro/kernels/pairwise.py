@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: Eq. 9 pairwise client distance with fused epilogue.
+"""Pallas TPU kernel: Eq. 9 pairwise client distance.
 
     D[u, k] = arccos( <Δb_u, Δb_k> / (|Δb_u||Δb_k|) ) + λ |Ĥ_u − Ĥ_k|
 
@@ -6,10 +6,14 @@ Inputs are the (N, C) bias-update matrix (C = classes/vocab, up to
 256k) and a per-row stats vector (N, 2) = [L2 norm, Ĥ] — both produced
 in ONE streaming pass by ``fused_stats``.  The kernel tiles the Gram
 product X Xᵀ for the MXU — (BN, BC) × (BC, BN) partial products
-accumulated in a VMEM f32 scratch over the C grid axis — and applies
-the normalize→clip→arccos→+λ|ΔĤ| epilogue on the last C block, so the
-(N, N) result is written to HBM exactly once and no (N, N) cosine
-intermediate ever exists.
+accumulated in a VMEM f32 scratch over the C grid axis — and writes the
+clipped cosine on the last C block, so the (N, N) result is written to
+HBM exactly once.
+
+The angular tail (arccos, zeroing the true diagonal, +λ|ΔĤ|) runs in
+XLA in the jitted wrapper (:func:`gram_tail`): Mosaic has no lowering
+for ``acos``/``atan``, and the tail is O(N²) elementwise work next to
+the O(N²·C) Gram sweep.
 
 ``hics_selection_step_pallas`` is the end-to-end fused selection step:
 it pads (N, C) ONCE, runs the fused stats sweep, and feeds the outputs
@@ -31,22 +35,30 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.fused_stats import _fused_stats_padded
 
 
-def _pairwise_kernel(x_ref, xt_ref, stats_ref, statsT_ref,
-                     o_ref, acc_ref, *, lam, eps, block_n):
+def _gram_tile(a_ref, b_ref) -> jnp.ndarray:
+    """f32 partial Gram a·bᵀ of two (rows, BC) VMEM tiles.  f32 operands
+    ask for Mosaic's fp32 contract precision: its default is one bf16
+    pass, which put the C=10 arccos ~5e-3 off the f32 oracle on a v5e.
+    bf16 operands (``gram_in_bf16``) keep the single native pass."""
+    a, b = a_ref[...], b_ref[...]
+    precision = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+                 else None)
+    return jax.lax.dot_general(
+        a.astype(jnp.float32), b.astype(jnp.float32),
+        (((1,), (1,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32)
+
+
+def _pairwise_kernel(x_ref, xt_ref, stats_ref, statsT_ref, o_ref,
+                     acc_ref, *, eps):
     ci = pl.program_id(2)
     nc = pl.num_programs(2)
-    i = pl.program_id(0)
-    j = pl.program_id(1)
 
     @pl.when(ci == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = x_ref[...].astype(jnp.float32)       # (BN, BC) rows tile
-    b = xt_ref[...].astype(jnp.float32)      # (BN, BC) cols tile
-    acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += _gram_tile(x_ref, xt_ref)    # rows × cols tiles
 
     @pl.when(ci == nc - 1)
     def _epilogue():
@@ -54,22 +66,32 @@ def _pairwise_kernel(x_ref, xt_ref, stats_ref, statsT_ref,
         nr = stats_ref[..., 0:1].astype(jnp.float32)      # (BN, 1)
         ncol = statsT_ref[..., 0:1].astype(jnp.float32)   # (BN, 1)
         denom = jnp.maximum(nr, eps) * jnp.maximum(ncol, eps).T
-        cos = acc_ref[...] / denom
-        cos = jnp.clip(cos, -1.0 + 1e-7, 1.0 - 1e-7)
-        ang = jnp.arccos(cos)
-        # zero the true diagonal (only on diagonal tiles)
-        row = i * block_n + jax.lax.broadcasted_iota(jnp.int32, ang.shape, 0)
-        col = j * block_n + jax.lax.broadcasted_iota(jnp.int32, ang.shape, 1)
-        ang = jnp.where(row == col, 0.0, ang)
-        hr = stats_ref[..., 1:2].astype(jnp.float32)      # (BN, 1)
-        hc = statsT_ref[..., 1:2].astype(jnp.float32)     # (BN, 1)
-        o_ref[...] = ang + lam * jnp.abs(hr - hc.T)
+        o_ref[...] = jnp.clip(acc_ref[...] / denom, -1.0 + 1e-7,
+                              1.0 - 1e-7)
 
 
-def _pairwise_padded(x: jnp.ndarray, stats: jnp.ndarray, lam: float,
-                     eps: float, bn: int, block_c: int,
+def gram_tail(g: jnp.ndarray, row_ids: jnp.ndarray, h_rows: jnp.ndarray,
+              h_cols: jnp.ndarray, lam: float,
+              epilogue: str = "arccos") -> jnp.ndarray:
+    """XLA tail of the Gram kernels: (R, N) kernel output -> distance.
+
+    ``g`` is the clipped cosine ("arccos"/"cosine") or the L2 distance
+    ("l2").  The cosine family takes the arccos; every epilogue zeroes
+    the true diagonal (strip row ``row_ids[r]`` against column index
+    r'), and "arccos" adds Eq. 9's λ|Ĥ_row − Ĥ_col|.
+    """
+    d = g if epilogue == "l2" else jnp.arccos(g)
+    d = jnp.where(row_ids[:, None] == jnp.arange(g.shape[1])[None, :],
+                  0.0, d)
+    if epilogue == "arccos":
+        d = d + lam * jnp.abs(h_rows[:, None] - h_cols[None, :])
+    return d
+
+
+def _pairwise_padded(x: jnp.ndarray, stats: jnp.ndarray, eps: float,
+                     bn: int, block_c: int,
                      interpret: bool) -> jnp.ndarray:
-    """Gram/arccos kernel on an already padded (n_pad, c_pad) buffer.
+    """Gram/cosine kernel on an already padded (n_pad, c_pad) buffer.
 
     ``stats`` is (n_pad, 2) = [norm, entropy]; padded rows must carry a
     nonzero norm.  The same buffer feeds the row and column tiles (two
@@ -79,8 +101,7 @@ def _pairwise_padded(x: jnp.ndarray, stats: jnp.ndarray, lam: float,
     c_pad = x.shape[1]
     grid = (n_pad // bn, n_pad // bn, c_pad // block_c)
     return pl.pallas_call(
-        functools.partial(_pairwise_kernel, lam=lam, eps=eps,
-                          block_n=bn),
+        functools.partial(_pairwise_kernel, eps=eps),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, block_c), lambda i, j, k: (i, k)),  # rows
@@ -120,8 +141,9 @@ def pairwise_distance_pallas(updates: jnp.ndarray, norms: jnp.ndarray,
                  constant_values=1.0)
     h = jnp.pad(entropies.astype(jnp.float32), (0, n_pad - n))
     stats = jnp.stack([nr, h], axis=-1)                  # (n_pad, 2)
-    out = _pairwise_padded(x, stats, lam, 1e-8, bn, block_c, interpret)
-    return out[:n, :n]
+    cos = _pairwise_padded(x, stats, 1e-8, bn, block_c, interpret)
+    h = h[:n]
+    return gram_tail(cos[:n, :n], jnp.arange(n), h, h, lam)
 
 
 @functools.partial(jax.jit,
@@ -136,7 +158,8 @@ def hics_selection_step_pallas(updates: jnp.ndarray, temperature: float,
     """Fused HiCS selection step: (N, C) -> (Ĥ (N,), Eq. 9 D (N, N)).
 
     One pad, one pre-Gram HBM sweep (the fused stats kernel), then the
-    Gram/arccos kernel on the same padded buffer — all inside one jit.
+    Gram kernel on the same padded buffer, then the XLA arccos tail —
+    all inside one jit.
     ``normalize=True`` adds a second stats sweep with rows scaled by
     1/RMS (the magnitude-invariant estimator); the unfused baseline had
     no kernel path for it at all.  ``gram_in_bf16`` halves Gram operand
@@ -157,5 +180,6 @@ def hics_selection_step_pallas(updates: jnp.ndarray, temperature: float,
     live = jnp.arange(n_pad) < n
     stats = jnp.stack([jnp.where(live, norm, 1.0), ent], axis=-1)
     xg = x.astype(jnp.bfloat16) if gram_in_bf16 else x
-    dist = _pairwise_padded(xg, stats, lam, 1e-8, bn, block_c, interpret)
-    return ent[:n], dist[:n, :n]
+    cos = _pairwise_padded(xg, stats, 1e-8, bn, block_c, interpret)
+    ent = ent[:n]
+    return ent, gram_tail(cos[:n, :n], jnp.arange(n), ent, ent, lam)

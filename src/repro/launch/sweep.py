@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.data import SyntheticSpec
 from repro.fed import LocalSpec
+from repro.launch.cache import enable_compile_cache
 from repro.scenarios import SCENARIOS, SweepSpec, bench_sweep, run_sweep
 
 
@@ -72,6 +73,7 @@ def main():
     ap.add_argument("--out", default="")
     ap.add_argument("--bench", default="BENCH_sweep.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     groups = ("selection", "training", "fairness") if args.telemetry else ()
 

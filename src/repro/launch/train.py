@@ -35,6 +35,7 @@ from repro.configs import get_config
 from repro.core import (head_bias_updates_stacked, head_num_classes,
                         make_selector)
 from repro.data import make_lm_streams
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import get_model
 from repro.optim import adam, apply_updates, clip_by_global_norm, sgd
@@ -100,6 +101,7 @@ def main():
     ap.add_argument("--out", default="")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -11,6 +11,7 @@ loop, vmapped sweep — ≥50 rounds), and under availability masking
 entropies or sampling weights).
 """
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -329,21 +330,26 @@ def _masked_drive(scenario_name, incremental, t_max=14, n=10, k=3, c=6,
     _, k_sel, round_keys = seed_keychain(seed, t_max)
     state = fn.init(k_sel)
     r = np.random.default_rng(seed)
+    # one compile for all rounds: called eagerly, every round compiled
+    # its own cond branches, and that many XLA:CPU compiles has
+    # segfaulted the compiler mid-suite
+    select = jax.jit(functools.partial(masked_select, fn))
+    update = jax.jit(fn.update)
     picks, states = [], []
     for t in range(t_max):
         kr = round_keys[t]
         k_s, _ = jax.random.split(kr)
         avail = availability_mask(scn, n, t, jax.random.fold_in(kr, 1))
         prev = state
-        ids, state = masked_select(fn, state, t, k_s, avail,
-                                   jax.random.fold_in(kr, 2))
+        ids, state = select(state, jnp.int32(t), k_s, avail,
+                            jax.random.fold_in(kr, 2))
         picks.append(np.asarray(ids).tolist())
         states.append((np.asarray(avail), np.asarray(prev.delta_b),
                        np.asarray(prev.row_stats), np.asarray(ids),
                        np.asarray(prev.stale_ids), state))
         obs = Observations(bias_updates=jnp.asarray(
             r.normal(size=(k, c)) * 0.02, jnp.float32))
-        state = fn.update(state, t, ids, obs)
+        state = update(state, jnp.int32(t), ids, obs)
     return picks, states, state
 
 

@@ -188,35 +188,40 @@ def cs_functional(num_clients: int, num_select: int, total_rounds: int,
                     state.feats, state.dist_cache, state.row_stats,
                     state.stale_ids, metric="cosine")
 
-            dist_c, stats_c = jax.lax.cond(
-                state.stale_fill > 0, _refresh,
-                lambda _: (state.dist_cache, state.row_stats), 0)
-            state = stale_clear(state._replace(
-                dist_cache=dist_c, row_stats=stats_c))
+            with jax.named_scope("strip"):
+                dist_c, stats_c = jax.lax.cond(
+                    state.stale_fill > 0, _refresh,
+                    lambda _: (state.dist_cache, state.row_stats), 0)
+                state = stale_clear(state._replace(
+                    dist_cache=dist_c, row_stats=stats_c))
 
         def warmup(key):
             # deterministic coverage like Alg. 1's first rounds
-            return coverage_sweep_device(key, state.seen, k)
+            with jax.named_scope("sample"):
+                return coverage_sweep_device(key, state.seen, k)
 
         def clustered(key):
-            if incremental:
-                ang = state.dist_cache
-            else:
-                f = state.feats
-                norms = jnp.linalg.norm(f, axis=-1, keepdims=True)
-                unit = f / jnp.clip(norms, 1e-8, None)
-                cos = jnp.clip(unit @ unit.T, -1.0 + 1e-7, 1.0 - 1e-7)
-                ang = jnp.arccos(cos)
-                ang = jnp.where(jnp.eye(n, dtype=bool), 0.0, ang)
-            # exactly symmetric by construction — skip re-symmetrizing
-            labels = agglomerate_device(ang, k, linkage="ward",
-                                        precomputed=True)
+            with jax.named_scope("cluster"):
+                if incremental:
+                    ang = state.dist_cache
+                else:
+                    f = state.feats
+                    norms = jnp.linalg.norm(f, axis=-1, keepdims=True)
+                    unit = f / jnp.clip(norms, 1e-8, None)
+                    cos = jnp.clip(unit @ unit.T, -1.0 + 1e-7, 1.0 - 1e-7)
+                    ang = jnp.arccos(cos)
+                    ang = jnp.where(jnp.eye(n, dtype=bool), 0.0, ang)
+                # exactly symmetric by construction — skip re-symmetrizing
+                labels = agglomerate_device(ang, k, linkage="ward",
+                                            precomputed=True)
             # one client per cluster, ∝ p_k within the cluster
-            logw = jnp.log(jnp.clip(state.weights, _LOG_FLOOR, None))
-            logit = jnp.where(labels[None, :] == jnp.arange(k)[:, None],
-                              logw[None, :], -jnp.inf)
-            g = jax.random.gumbel(key, (k, n), jnp.float32)
-            return jnp.argmax(logit + g, axis=1).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                logw = jnp.log(jnp.clip(state.weights, _LOG_FLOOR, None))
+                logit = jnp.where(
+                    labels[None, :] == jnp.arange(k)[:, None],
+                    logw[None, :], -jnp.inf)
+                g = jax.random.gumbel(key, (k, n), jnp.float32)
+                return jnp.argmax(logit + g, axis=1).astype(jnp.int32)
 
         ids = jax.lax.cond(state.unseen_count > 0, warmup, clustered, key)
         return ids, state
@@ -306,11 +311,12 @@ def divfl_functional(num_clients: int, num_select: int, total_rounds: int,
                     state.feats, state.dist_cache, state.row_stats,
                     state.stale_ids, metric="l2")
 
-            dist_c, stats_c = jax.lax.cond(
-                state.stale_fill > 0, _refresh,
-                lambda _: (state.dist_cache, state.row_stats), 0)
-            state = stale_clear(state._replace(
-                dist_cache=dist_c, row_stats=stats_c))
+            with jax.named_scope("strip"):
+                dist_c, stats_c = jax.lax.cond(
+                    state.stale_fill > 0, _refresh,
+                    lambda _: (state.dist_cache, state.row_stats), 0)
+                state = stale_clear(state._replace(
+                    dist_cache=dist_c, row_stats=stats_c))
 
         def cold(key):
             if selected_only:
@@ -351,7 +357,8 @@ def divfl_functional(num_clients: int, num_select: int, total_rounds: int,
 
         warm_ok = (state.unseen_count == 0 if selected_only
                    else state.hist_count > 0)
-        ids = jax.lax.cond(warm_ok, warm, cold, key)
+        with jax.named_scope("sample"):
+            ids = jax.lax.cond(warm_ok, warm, cold, key)
         return ids, state
 
     def update(state, t, ids, obs):

@@ -91,31 +91,36 @@ def hics_functional(num_clients: int, num_select: int, total_rounds: int,
                     normalize=normalize, gram_in_bf16=gram_in_bf16)
                 return d, s
 
-            dist_c, stats_c = jax.lax.cond(
-                state.stale_fill > 0, _refresh,
-                lambda _: (state.dist_cache, state.row_stats), 0)
-            state = stale_clear(state._replace(
-                dist_cache=dist_c, row_stats=stats_c))
+            with jax.named_scope("strip"):
+                dist_c, stats_c = jax.lax.cond(
+                    state.stale_fill > 0, _refresh,
+                    lambda _: (state.dist_cache, state.row_stats), 0)
+                state = stale_clear(state._replace(
+                    dist_cache=dist_c, row_stats=stats_c))
 
         def sweep(key):
-            ids = coverage_sweep_device(key, state.seen, k)
-            return ids, state.seen.at[ids].set(True)
+            with jax.named_scope("sample"):
+                ids = coverage_sweep_device(key, state.seen, k)
+                return ids, state.seen.at[ids].set(True)
 
         def clustered(key):
-            if incremental:
-                ent, dist = state.row_stats[:, 1], state.dist_cache
-            else:
-                ent, dist = hics_selection_step(
-                    state.delta_b, temperature, lam=lam,
-                    normalize=normalize, gram_in_bf16=gram_in_bf16)
-            # the cache scatter (and the fused kernel) keep the matrix
-            # exactly symmetric, so clustering may skip re-symmetrizing
-            labels = agglomerate_device(dist, m, linkage=linkage,
-                                        precomputed=True)
-            means = cluster_means_device(ent, labels, m)
-            gamma_t = anneal_device(gamma0, t, tr)
-            ids = hierarchical_sample_device(
-                key, labels, means, state.weights, k, gamma_t)
+            with jax.named_scope("cluster"):
+                if incremental:
+                    ent, dist = state.row_stats[:, 1], state.dist_cache
+                else:
+                    ent, dist = hics_selection_step(
+                        state.delta_b, temperature, lam=lam,
+                        normalize=normalize, gram_in_bf16=gram_in_bf16)
+                # the cache scatter (and the fused kernel) keep the
+                # matrix exactly symmetric, so clustering may skip
+                # re-symmetrizing
+                labels = agglomerate_device(dist, m, linkage=linkage,
+                                            precomputed=True)
+                means = cluster_means_device(ent, labels, m)
+            with jax.named_scope("sample"):
+                gamma_t = anneal_device(gamma0, t, tr)
+                ids = hierarchical_sample_device(
+                    key, labels, means, state.weights, k, gamma_t)
             return ids, state.seen
 
         ids, seen = jax.lax.cond(state.unseen_count > 0, sweep,

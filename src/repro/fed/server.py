@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+import weakref
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -57,7 +58,8 @@ from repro.core.selectors.functional import state_entropies
 from repro.fed.client import (LocalSpec, init_extra, make_eval_fn,
                               make_local_update)
 from repro.telemetry import (MetricsSpec, TelemetryCtx, client_true_entropy,
-                             make_metrics, trace_span)
+                             make_metrics, register_program, trace_enabled,
+                             trace_span)
 
 #: requirements the scanned round loop can satisfy on-device.  All four
 #: are computable inside the jitted round step: loss_all is a vmapped
@@ -356,40 +358,52 @@ class FederatedServer:
                 t, kr, kg = xs
             else:
                 t, kr = xs
-            k_sel, k_loc = jax.random.split(kr)
-            ids, sstate = fn.select(sstate, t, k_sel)
-            rngs = jax.random.split(k_loc, cfg.num_select)
-            decay = jnp.float32(cfg.lr_decay) ** (t // cfg.lr_decay_every)
-            ex_sel = (_tree_stack_gather(extras, ids) if has_extras
-                      else {})
-            params_before = params
-            new_params, new_extras, metrics = lu_v(
-                params, ex_sel, self.x[ids], self.y[ids], self.mask[ids],
-                rngs, decay)
-            if has_extras:
-                extras = _tree_stack_scatter(extras, ids, new_extras)
-            bias_updates = head_bias_updates_stacked(params, new_params)
-            params = aggregate_params(new_params)
+            # each phase carries a named scope: HLO metadata only, so
+            # the compiled program is the same with or without tracing,
+            # and a device trace's operations map back to their phase
+            with jax.named_scope("select"):
+                k_sel, k_loc = jax.random.split(kr)
+                ids, sstate = fn.select(sstate, t, k_sel)
+            with jax.named_scope("local"):
+                rngs = jax.random.split(k_loc, cfg.num_select)
+                decay = jnp.float32(cfg.lr_decay) ** (
+                    t // cfg.lr_decay_every)
+                ex_sel = (_tree_stack_gather(extras, ids) if has_extras
+                          else {})
+                params_before = params
+                new_params, new_extras, metrics = lu_v(
+                    params, ex_sel, self.x[ids], self.y[ids],
+                    self.mask[ids], rngs, decay)
+                if has_extras:
+                    extras = _tree_stack_scatter(extras, ids, new_extras)
+            with jax.named_scope("delta_b"):
+                bias_updates = head_bias_updates_stacked(params,
+                                                         new_params)
+            with jax.named_scope("aggregate"):
+                params = aggregate_params(new_params)
             losses = full_updates = None
-            if need_losses:
-                losses, _ = self._eval_vmapped(params, self.x, self.y,
-                                               self.mask)
-            if need_full_all:
-                full_updates = self._grad_all(
-                    params, self.x, self.y, self.mask,
-                    jax.random.split(kg, cfg.num_clients))
-            elif need_full_sel:
-                full_updates = full_sel_updates(params, new_params)
-            sstate = fn.update(sstate, t, ids, Observations(
-                bias_updates=bias_updates, full_updates=full_updates,
-                losses=losses))
-            train_loss = jnp.mean(metrics["train_loss"])
-            telc, tel = tel_step(telc, TelemetryCtx(
-                t=t, ids=ids, state=sstate, train_loss=train_loss,
-                true_entropy=true_ent, params_before=params_before,
-                params_after=params, bias_updates=bias_updates,
-                lr_scale=decay))
-            ent = state_entropies(fn, sstate)
+            with jax.named_scope("observe"):
+                if need_losses:
+                    losses, _ = self._eval_vmapped(params, self.x, self.y,
+                                                   self.mask)
+                if need_full_all:
+                    full_updates = self._grad_all(
+                        params, self.x, self.y, self.mask,
+                        jax.random.split(kg, cfg.num_clients))
+                elif need_full_sel:
+                    full_updates = full_sel_updates(params, new_params)
+            with jax.named_scope("selector_update"):
+                sstate = fn.update(sstate, t, ids, Observations(
+                    bias_updates=bias_updates, full_updates=full_updates,
+                    losses=losses))
+            with jax.named_scope("telemetry"):
+                train_loss = jnp.mean(metrics["train_loss"])
+                telc, tel = tel_step(telc, TelemetryCtx(
+                    t=t, ids=ids, state=sstate, train_loss=train_loss,
+                    true_entropy=true_ent, params_before=params_before,
+                    params_after=params, bias_updates=bias_updates,
+                    lr_scale=decay))
+                ent = state_entropies(fn, sstate)
             out = (ids, train_loss, ent, tel)
             return (params, extras, sstate, telc), out
 
@@ -405,9 +419,11 @@ class FederatedServer:
                 f"(needs host-side {sorted(unmet)})")
         if self._round_step is None:
             self._round_step = self._make_round_step()
+        register = self._scan_jit is None and trace_enabled()
         if self._scan_jit is None:
-            self._scan_jit = jax.jit(
-                lambda carry, xs: jax.lax.scan(self._round_step, carry, xs))
+            def scan_segment(carry, xs):
+                return jax.lax.scan(self._round_step, carry, xs)
+            self._scan_jit = jax.jit(scan_segment)
         carry = (self.params, self._extras, self.selector.state,
                  self._telc)
         # segments of eval_every rounds; evaluation lands after each
@@ -417,46 +433,80 @@ class FederatedServer:
         seg_len = cfg.eval_every if self.test is not None else cfg.rounds
         need_gk = "full_all" in fn.requires
         t = 0
-        while t < cfg.rounds:
-            n = min(seg_len, cfg.rounds - t)
-            keys, gkeys = [], []
-            for _ in range(n):       # same key chain as the host loop:
-                self.rng, kr = jax.random.split(self.rng)
-                keys.append(kr)
-                if need_gk:          # ... kr then the grad-poll key
-                    self.rng, kg = jax.random.split(self.rng)
-                    gkeys.append(kg)
-            ts = jnp.arange(t, t + n, dtype=jnp.int32)
-            xs = ((ts, jnp.stack(keys), jnp.stack(gkeys)) if need_gk
-                  else (ts, jnp.stack(keys)))
-            t_start = time.perf_counter()
-            with trace_span(f"fed/scan_segment[{n}]"):
-                carry, (ids_seg, loss_seg, ent_seg, tel_seg) = \
-                    self._scan_jit(carry, xs)
-                jax.block_until_ready(carry)
-            # per-SEGMENT wall time: rounds never surface to the host
-            # here, so a per-round number would be fiction (the old
-            # code wrote the segment mean into every round's wall_s)
-            self.history["segment_wall_s"].append(
-                time.perf_counter() - t_start)
-            self.history["segment_rounds"].append(n)
-            ids_np = np.asarray(ids_seg)
-            loss_np = np.asarray(loss_seg)
-            ent_np = np.asarray(ent_seg)
-            for i in range(n):
-                self.history["round"].append(t + i)
-                self.history["train_loss"].append(float(loss_np[i]))
-                self.history["selected"].append(ids_np[i].tolist())
-                self.history["bias_entropy"].append(
-                    ent_np[i].tolist() if ent_np.shape[-1] else None)
-            self._tel_segments.append(jax.tree_util.tree_map(
-                np.asarray, tel_seg))
-            t += n
-            (self.params, self._extras, self.selector.state,
-             self._telc) = carry
-            if self.test is not None:
-                self._eval_round(t - 1, progress)
+        with trace_span("fed/run"):
+            while t < cfg.rounds:
+                n = min(seg_len, cfg.rounds - t)
+                with trace_span("fed/keys"):
+                    # same key chain as the host loop: kr, then the
+                    # grad-poll key
+                    keys, gkeys = [], []
+                    for _ in range(n):
+                        self.rng, kr = jax.random.split(self.rng)
+                        keys.append(kr)
+                        if need_gk:
+                            self.rng, kg = jax.random.split(self.rng)
+                            gkeys.append(kg)
+                    ts = jnp.arange(t, t + n, dtype=jnp.int32)
+                    xs = ((ts, jnp.stack(keys), jnp.stack(gkeys))
+                          if need_gk else (ts, jnp.stack(keys)))
+                if register:
+                    self._register_scan_program(carry, xs)
+                    register = False
+                t_start = time.perf_counter()
+                with trace_span(f"fed/scan_segment[{n}]"):
+                    carry, (ids_seg, loss_seg, ent_seg, tel_seg) = \
+                        self._scan_jit(carry, xs)
+                    jax.block_until_ready(carry)
+                # per-SEGMENT wall time: rounds never surface to the
+                # host here, so a per-round number would be fiction
+                self.history["segment_wall_s"].append(
+                    time.perf_counter() - t_start)
+                self.history["segment_rounds"].append(n)
+                with trace_span("fed/history"):
+                    ids_np = np.asarray(ids_seg)
+                    loss_np = np.asarray(loss_seg)
+                    ent_np = np.asarray(ent_seg)
+                    for i in range(n):
+                        self.history["round"].append(t + i)
+                        self.history["train_loss"].append(
+                            float(loss_np[i]))
+                        self.history["selected"].append(ids_np[i].tolist())
+                        self.history["bias_entropy"].append(
+                            ent_np[i].tolist() if ent_np.shape[-1]
+                            else None)
+                    self._tel_segments.append(jax.tree_util.tree_map(
+                        np.asarray, tel_seg))
+                t += n
+                (self.params, self._extras, self.selector.state,
+                 self._telc) = carry
+                if self.test is not None:
+                    with trace_span("fed/eval"):
+                        self._eval_round(t - 1, progress)
         return self._finish()
+
+    def _register_scan_program(self, carry, xs) -> None:
+        """Hand the tracing module a thunk that compiles ``scan_segment``
+        for these arguments' shapes and returns its optimized HLO text,
+        from which a trace's device operations map to their scopes.  It
+        holds shapes and a weak reference to the server, no arrays, and
+        runs only when a reader asks (the compile cache usually has the
+        program by then)."""
+        def spec(a):    # the jit cache's own key: no sharding unless
+            # committed, so asking for the text finds the run's program
+            committed = getattr(a, "committed", False)
+            return jax.ShapeDtypeStruct(
+                a.shape, a.dtype, weak_type=getattr(a, "weak_type", False),
+                sharding=a.sharding if committed else None)
+
+        specs = jax.tree_util.tree_map(spec, (carry, xs))
+        server = weakref.ref(self)
+
+        def hlo_text() -> Optional[str]:
+            s = server()
+            if s is None:
+                return None
+            return s._scan_jit.lower(*specs).compile().as_text()
+        register_program("scan_segment", hlo_text)
 
     # ------------------------------------------------------------------
     def _eval_round(self, t: int, progress: bool) -> None:

@@ -110,5 +110,6 @@ def decode_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((g, dh), jnp.float32),
         ],
         interpret=interpret,
+        name="gqa_decode_attention",
     )(lengths, qg, kt, vt)
     return out.reshape(B, H, dh)

@@ -123,6 +123,7 @@ def _fused_stats_padded(x: jnp.ndarray, scale_col: jnp.ndarray,
             pltpu.VMEM((block_n, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_row_stats",
     )(x, scale_col)
     return ent[:, 0], norm[:, 0], rms[:, 0]
 

@@ -129,6 +129,7 @@ def _gram_strip(x_pad: jnp.ndarray, stats: jnp.ndarray, ids: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((k_pad, n_pad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((_BK, bn), jnp.float32)],
         interpret=interpret,
+        name="gram_strip",
     )(rows, x_pad, stats_rows, stats_all)
     return gram_tail(g[:k, :n], ids, stats[ids, 1], stats[:, 1], lam,
                      epilogue)

@@ -95,5 +95,6 @@ def entropy_pallas(updates: jnp.ndarray, temperature: float,
             pltpu.VMEM((block_n, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="estimate_entropies",
     )(x)
     return out[:n, 0]

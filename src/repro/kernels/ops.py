@@ -16,6 +16,12 @@ validated against ``ref.py`` by the test suite, and
                                             (arccos / cosine / l2)
     pairwise_distances(updates, T, lam)     (N, C) -> (N, N)   [Eq. 9]
     gqa_decode_attention(q, k, v, length)   one-token flash decode
+
+Each entry point runs under a ``jax.named_scope`` of its own name, and
+each Pallas kernel carries a ``name=`` (``gram_strip``,
+``pairwise_gram``, ``fused_row_stats``, ``estimate_entropies``,
+``gqa_decode_attention``), so the compiled program's metadata says
+which entry point and kernel an operation belongs to.
 """
 from __future__ import annotations
 
@@ -33,16 +39,13 @@ from repro.kernels.gram_update import (cached_feature_step_pallas,
 from repro.kernels.hetero_entropy import entropy_pallas
 from repro.kernels.pairwise import (hics_selection_step_pallas,
                                     pairwise_distance_pallas)
-# profiler span labels (exact no-ops unless REPRO_TRACE=1); trace.py is
-# a leaf module, so this import closes no cycle with repro.core
-from repro.telemetry.trace import annotate
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-@annotate("kernels/estimate_entropies")
+@jax.named_scope("estimate_entropies")
 def estimate_entropies(updates: jnp.ndarray, temperature: float,
                        use_pallas: bool | None = None) -> jnp.ndarray:
     """Ĥ over N clients' bias updates; Pallas on TPU, oracle on CPU."""
@@ -53,7 +56,7 @@ def estimate_entropies(updates: jnp.ndarray, temperature: float,
     return ref.entropy_ref(updates, temperature)
 
 
-@annotate("kernels/fused_row_stats")
+@jax.named_scope("fused_row_stats")
 def fused_row_stats(updates: jnp.ndarray, temperature: float,
                     use_pallas: bool | None = None):
     """(Ĥ, |Δb|₂, RMS) per client in one HBM sweep over (N, C)."""
@@ -64,7 +67,7 @@ def fused_row_stats(updates: jnp.ndarray, temperature: float,
     return ref.fused_stats_ref(updates, temperature)
 
 
-@annotate("kernels/hics_selection_step")
+@jax.named_scope("hics_selection_step")
 def hics_selection_step(updates: jnp.ndarray, temperature: float,
                         lam: float = 10.0, normalize: bool = False,
                         gram_in_bf16: bool = False,
@@ -92,7 +95,7 @@ def _selection_step_ref_jit(updates, temperature, lam, normalize):
                                   normalize=normalize)
 
 
-@annotate("kernels/hics_selection_step_cached")
+@jax.named_scope("hics_selection_step_cached")
 def hics_selection_step_cached(updates: jnp.ndarray, dist: jnp.ndarray,
                                stats: jnp.ndarray, ids: jnp.ndarray,
                                temperature: float, lam: float = 10.0,
@@ -133,7 +136,7 @@ def _cached_step_ref_jit(updates, dist, stats, ids, temperature, lam,
                                          normalize=normalize)
 
 
-@annotate("kernels/gram_row_update")
+@jax.named_scope("gram_row_update")
 def gram_row_update(updates: jnp.ndarray, stats: jnp.ndarray,
                     ids: jnp.ndarray, lam: float = 10.0,
                     gram_in_bf16: bool = False,
@@ -160,7 +163,7 @@ def _gram_row_update_lax(updates, stats, ids, lam, epilogue):
                                   epilogue=epilogue)
 
 
-@annotate("kernels/cached_feature_step")
+@jax.named_scope("cached_feature_step")
 def cached_feature_step(feats: jnp.ndarray, dist: jnp.ndarray,
                         stats: jnp.ndarray, ids: jnp.ndarray,
                         metric: str = "cosine",
@@ -197,7 +200,7 @@ def _cached_feature_step_ref_jit(feats, dist, stats, ids, metric):
                                        metric=metric)
 
 
-@annotate("kernels/pairwise_distances")
+@jax.named_scope("pairwise_distances")
 def pairwise_distances(updates: jnp.ndarray, temperature: float,
                        lam: float = 10.0,
                        use_pallas: bool | None = None) -> jnp.ndarray:
@@ -212,7 +215,7 @@ def pairwise_distances(updates: jnp.ndarray, temperature: float,
     return ref.pairwise_distance_ref(updates, h, lam)
 
 
-@annotate("kernels/gqa_decode_attention")
+@jax.named_scope("gqa_decode_attention")
 def gqa_decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                          length, scale: float | None = None,
                          use_pallas: bool | None = None) -> jnp.ndarray:

@@ -113,6 +113,7 @@ def _pairwise_padded(x: jnp.ndarray, stats: jnp.ndarray, eps: float,
         out_shape=jax.ShapeDtypeStruct((n_pad, n_pad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bn, bn), jnp.float32)],
         interpret=interpret,
+        name="pairwise_gram",
     )(x, x, stats, stats)
 
 

@@ -15,10 +15,13 @@ Three pieces, one discipline (nothing leaves the device mid-scan):
                and stamps environment metadata (jax version, backend,
                git SHA) into benchmark artifacts so the bench gate can
                refuse cross-machine comparisons.
-  trace.py  — ``jax.profiler`` span annotations behind the
-               ``REPRO_TRACE=1`` env switch; the Pallas kernel call
-               sites and the drivers' scan segments are wrapped, so
-               ``jax.profiler.trace`` dumps are labeled by subsystem.
+  trace.py  — ``jax.profiler`` host spans behind the
+               ``REPRO_TRACE=1`` env switch (the drivers' phases:
+               ``fed/keys``, ``fed/scan_segment[n]``, ``fed/history``,
+               ``fed/eval``), and the scanned loop's optimized HLO
+               text (``program_text``), whose ``jax.named_scope``
+               metadata maps a device trace's operations to the
+               round's phases.
 
 See docs/observability.md for the full tour.
 """
@@ -28,12 +31,13 @@ from repro.telemetry.export import (env_stamp, read_jsonl, records_from_telemetr
 from repro.telemetry.metrics import (GROUPS, Metrics, MetricsSpec,
                                      TelemetryCtx, client_true_entropy,
                                      make_metrics)
-from repro.telemetry.trace import annotate, trace_enabled, trace_span
+from repro.telemetry.trace import (program_text, register_program,
+                                   trace_enabled, trace_span)
 
 __all__ = [
     "GROUPS", "Metrics", "MetricsSpec", "TelemetryCtx",
     "client_true_entropy", "make_metrics",
     "env_stamp", "read_jsonl", "records_from_telemetry", "summarize",
     "telemetry_from_records", "write_jsonl", "write_run", "write_sweep",
-    "annotate", "trace_enabled", "trace_span",
+    "program_text", "register_program", "trace_enabled", "trace_span",
 ]

@@ -18,10 +18,15 @@ and reproduces the naive flat-argmin tie order exactly.  Each merge is
 then O(N) amortized with ~a dozen vector ops, no per-merge boolean-mask
 copies, and no (N, N) argmin.  Rows retired by a merge are parked at
 +inf so inactive entries never win.
+
+:func:`agglomerate_device` runs the same merge loop in float32 inside
+``jit``: the same cache and pick-time check (a ``while_loop``), so the
+same merge order and the same Lance–Williams values, and O(N) work per
+merge with no pass over the (N, N) matrix.
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -142,16 +147,37 @@ def agglomerate_device(dist: jnp.ndarray, num_clusters: int,
     """Pure-jax agglomerative clustering — jit/scan/vmap-compatible.
 
     Same Lance–Williams semantics as :func:`agglomerate` (ward on
-    squared distances, naive flat-argmin merge order, first-appearance
-    relabelling) but with fixed shapes: N − M merges unrolled in a
-    ``fori_loop``, retired rows parked at +inf.  Because merges always
-    absorb the higher index into the lower, each surviving
-    representative r first appears in the label vector at position r —
-    so first-appearance relabelling is exactly the rank of r among the
-    sorted representatives, which ``unique(size=M)`` + ``searchsorted``
-    computes with static shapes.  O(N³) worst case versus the numpy
-    version's amortized O(N²), but it runs on-device inside the jitted
-    round loop (N ≤ a few thousand in any selection scenario).
+    squared distances, flat-argmin merge order, first-appearance
+    relabelling) in float32 with fixed shapes: N − M merges in a
+    ``fori_loop``, retired clusters parked at +inf.
+
+    Each merge is O(N): it follows :func:`agglomerate` step for step.
+    A per-row minimum cache, always a lower bound on the row's true
+    minimum, picks the row ``p = argmin(row_min)``; a small
+    ``while_loop`` checks it against the row's true minimum and, while
+    the cache was stale-low, repairs that entry and picks again (about
+    1.5 repairs a merge on Eq. 9 matrices).  A checked row's minimum is
+    ≤ every other row's lower bound, and every earlier row's bound is
+    strictly larger, so ``p`` is the first row that holds the global
+    minimum and ``q = argmin`` of that row the first column in it:
+    exactly the pair (p < q) of the row-major argmin over the whole
+    matrix, ties included.  Each of those minima is one reduction that
+    also returns the winner's write stamp and size, so a merge runs
+    about a dozen small ops plus five a repair.
+
+    A merge writes one row of the matrix, the merged cluster's, and no
+    column: a column write next to a row write makes the TPU compiler
+    keep the matrix in two layouts and copy all of it twice a merge.
+    A row is read as it stands instead: entry c of row k comes from
+    row k or from row c, whichever was written later (``stamp``), and
+    retired clusters (size 0) read +inf.  No op inside the merge loop
+    passes over the (N, N) matrix.
+
+    Because merges always absorb the higher index into the lower, each
+    surviving representative r first appears in the label vector at
+    position r — so first-appearance relabelling is exactly the rank of
+    r among the sorted representatives, which ``unique(size=M)`` +
+    ``searchsorted`` computes with static shapes.
 
     ``precomputed=True`` is the fast path for callers holding an
     already exactly-symmetric distance — the incremental selection
@@ -160,6 +186,31 @@ def agglomerate_device(dist: jnp.ndarray, num_clusters: int,
     ``0.5·(x + x)`` is bit-exact ``x`` in f32, so the flag can never
     change labels; it only removes work.
     """
+    return _agglomerate_device(dist, num_clusters, linkage,
+                               precomputed)[0]
+
+
+class _Pick(NamedTuple):
+    """One pick of the merge loop: row ``p`` with the least cached
+    minimum ``low``, its true minimum at its first column ``q``, what
+    the merge needs of both rows, and the repairs made so far."""
+    row_min: jnp.ndarray
+    low: jnp.ndarray
+    p: jnp.ndarray
+    stamp_p: jnp.ndarray
+    n_p: jnp.ndarray
+    true_min: jnp.ndarray
+    q: jnp.ndarray
+    stamp_q: jnp.ndarray
+    n_q: jnp.ndarray
+    repairs: jnp.ndarray
+
+
+def _agglomerate_device(dist: jnp.ndarray, num_clusters: int,
+                        linkage: str = "ward", precomputed: bool = False
+                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`agglomerate_device` plus the number of stale-row repairs
+    the pick loop made over all merges (an int32 scalar)."""
     if linkage not in _LINKAGES:
         raise ValueError(f"linkage must be one of {_LINKAGES}")
     n = dist.shape[0]
@@ -171,13 +222,54 @@ def agglomerate_device(dist: jnp.ndarray, num_clusters: int,
         d = d * d
     d = jnp.where(jnp.eye(n, dtype=bool), jnp.inf, d)
 
-    def body(_, carry):
-        d, sizes, labels = carry
-        flat = jnp.argmin(d)                 # row-major ⇒ i < j
-        i, j = flat // n, flat % n
-        dij = d[i, j]
-        ni, nj = sizes[i], sizes[j]
-        di, dj = d[i], d[j]
+    idx = jnp.arange(n)
+
+    def first_min(x, *rest):
+        # (min of x, first index holding it, rest[...] at that index) in
+        # one reduction: the argmin's own tie order, plus what the merge
+        # needs to know of the winner without a gather of its own
+        def keep(a, b):
+            take = (a[0] < b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
+            return tuple(jnp.where(take, u, v) for u, v in zip(a, b))
+        init = (jnp.float32(jnp.inf), jnp.int32(n)) + tuple(
+            jnp.zeros((), r.dtype) for r in rest)
+        return jax.lax.reduce((x, idx) + rest, init, keep, (0,))
+
+    def row(d, stamp, sizes, k, stamp_k):
+        # row k as it stands: entry c lives in row k if row k was
+        # written after row c, else in row c (read as column k);
+        # retired clusters read +inf
+        fresh = jnp.where(stamp > stamp_k, d[:, k], d[k])
+        return jnp.where(sizes > 0, fresh, jnp.inf)
+
+    def body(t, carry):
+        d, stamp, row_min, sizes, labels, repairs = carry
+
+        def look(row_min, repairs):
+            low, p, stamp_p, n_p = first_min(row_min, stamp, sizes)
+            true_min, q, stamp_q, n_q = first_min(
+                row(d, stamp, sizes, p, stamp_p), stamp, sizes)
+            return _Pick(row_min, low, p, stamp_p, n_p, true_min, q,
+                         stamp_q, n_q, repairs)
+
+        # the cache is a lower bound, so a picked row is stale exactly
+        # when its cached value is below the row's true minimum; the
+        # strict test also ends the loop on NaN input
+        def repair(c):
+            return look(jnp.where(idx == c.p, c.true_min, c.row_min),
+                        c.repairs + 1)
+
+        (row_min, _, p, stamp_p, n_p, dij, q, stamp_q, n_q,
+         repairs) = jax.lax.while_loop(lambda c: c.low < c.true_min,
+                                       repair, look(row_min, repairs))
+        dp = row(d, stamp, sizes, p, stamp_p)
+        dq = row(d, stamp, sizes, q, stamp_q)
+        # p < q on symmetric input; ordering keeps merges absorbing the
+        # higher index whatever the input
+        swap = p > q
+        i, j = jnp.where(swap, q, p), jnp.where(swap, p, q)
+        di, dj = jnp.where(swap, dq, dp), jnp.where(swap, dp, dq)
+        ni, nj = jnp.where(swap, n_q, n_p), jnp.where(swap, n_p, n_q)
         if linkage == "ward":
             new = ((ni + sizes) * di + (nj + sizes) * dj
                    - sizes * dij) / (ni + nj + sizes)
@@ -187,18 +279,23 @@ def agglomerate_device(dist: jnp.ndarray, num_clusters: int,
             new = jnp.maximum(di, dj)
         else:  # single
             new = jnp.minimum(di, dj)
-        new = new.at[i].set(jnp.inf).at[j].set(jnp.inf)
-        d = d.at[i, :].set(new).at[:, i].set(new)
-        d = d.at[j, :].set(jnp.inf).at[:, j].set(jnp.inf)
-        sizes = sizes.at[i].set(ni + nj).at[j].set(0.0)
+        is_i, is_j = idx == i, idx == j
+        new = jnp.where(is_i | is_j, jnp.inf, new)
+        d = jax.lax.dynamic_update_slice(d, new[None, :], (i, 0))
+        row_min = jnp.where(is_i, jnp.min(new),
+                            jnp.where(is_j, jnp.inf,
+                                      jnp.minimum(row_min, new)))
+        sizes = jnp.where(is_i, ni + nj, jnp.where(is_j, 0.0, sizes))
+        stamp = jnp.where(is_i, t + 1, stamp)
         labels = jnp.where(labels == j, i, labels)
-        return d, sizes, labels
+        return d, stamp, row_min, sizes, labels, repairs
 
-    _, _, labels = jax.lax.fori_loop(
+    _, _, _, _, labels, repairs = jax.lax.fori_loop(
         0, n - num_clusters, body,
-        (d, jnp.ones(n, jnp.float32), jnp.arange(n)))
+        (d, jnp.zeros(n, jnp.int32), jnp.min(d, axis=1),
+         jnp.ones(n, jnp.float32), idx, jnp.zeros((), jnp.int32)))
     reps = jnp.unique(labels, size=num_clusters)
-    return jnp.searchsorted(reps, labels).astype(jnp.int32)
+    return jnp.searchsorted(reps, labels).astype(jnp.int32), repairs
 
 
 def cluster_means_device(values: jnp.ndarray, labels: jnp.ndarray,

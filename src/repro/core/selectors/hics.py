@@ -37,7 +37,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.clustering import agglomerate_device, cluster_means_device
+from repro.core.clustering import (_agglomerate_device, agglomerate_device,
+                                   cluster_means_device)
 from repro.core.hetero import estimate_entropy
 from repro.core.sampling import (anneal_device, coverage_sweep_device,
                                  hierarchical_sample_device)
@@ -150,13 +151,15 @@ def hics_functional(num_clients: int, num_select: int, total_rounds: int,
         # clustering-health observables for the telemetry ``selection``
         # group: re-cluster the cached Eq. 9 distance (incremental path
         # only — from-scratch mode has no resident distance to read)
-        # and report cluster sizes + the within-cluster Ĥ RMS spread.
+        # and report cluster sizes, the within-cluster Ĥ RMS spread and
+        # how many stale rows the merge loop's min cache repaired.
         ent = state.row_stats[:, 1]
-        labels = agglomerate_device(state.dist_cache, m, linkage=linkage,
-                                    precomputed=True)
+        labels, repairs = _agglomerate_device(
+            state.dist_cache, m, linkage=linkage, precomputed=True)
         means = cluster_means_device(ent, labels, m)
         return {
             "cluster_sizes": jnp.bincount(labels, length=m),
+            "cluster_repairs": repairs,
             "cluster_ent_spread": jnp.sqrt(
                 jnp.mean(jnp.square(ent - means[labels]))),
         }

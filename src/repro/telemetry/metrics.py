@@ -31,7 +31,8 @@ Groups:
               correlation (the Eq. 9 estimation-quality observable;
               needs ``ctx.true_entropy``), distance-cache staleness
               fill, and — when the selector exposes ``diagnostics`` —
-              cluster sizes and within-cluster Ĥ spread.
+              cluster sizes, within-cluster Ĥ spread and the stale-row
+              repairs of one clustering of the cached distance.
   training  — per-round train loss, mean ‖Δb‖ row norm, global update
               norm ‖θ^{t+1} − θ^t‖, lr scale.
   fairness  — cumulative times-selected histogram, participation rate
@@ -218,9 +219,12 @@ def make_metrics(spec: MetricsSpec, fn=None, num_clients: int = 0,
                 diag["cluster_sizes"], jnp.int32)
             out["selection/cluster_ent_spread"] = _f32(
                 diag["cluster_ent_spread"])
+            out["selection/cluster_repairs"] = jnp.asarray(
+                diag["cluster_repairs"], jnp.int32)
         else:
             out["selection/cluster_sizes"] = _zi()
             out["selection/cluster_ent_spread"] = _zf()
+            out["selection/cluster_repairs"] = _zi()
 
         # -- training -----------------------------------------------------
         out["training/loss"] = (

@@ -12,6 +12,7 @@ from repro.core import (SELECTORS, Observations, agglomerate,
                         cluster_means_device, hierarchical_sample_device,
                         make_functional, make_selector,
                         weighted_sample_device)
+from repro.core.clustering import _agglomerate_device
 
 
 def _drive_functional(name, n, k, t_max, c, seed, db, full, losses):
@@ -145,16 +146,114 @@ def test_num_select_clamped_to_num_clients(name):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("linkage", ["ward", "average", "complete",
-                                     "single"])
-def test_agglomerate_device_matches_numpy(linkage, rng):
-    pts = rng.normal(size=(18, 3))
+LINKAGES = ("ward", "average", "complete", "single")
+
+
+def _agglomerate_flat(dist, num_clusters, linkage="ward"):
+    """Oracle: the merge loop ``agglomerate_device`` ran before its
+    row-minimum cache — each merge takes the row-major argmin over the
+    whole matrix and writes rows and columns i and j."""
+    n = dist.shape[0]
+    d = jnp.asarray(dist, jnp.float32)
+    d = 0.5 * (d + d.T)
+    if linkage == "ward":
+        d = d * d
+    d = jnp.where(jnp.eye(n, dtype=bool), jnp.inf, d)
+
+    def body(_, carry):
+        d, sizes, labels = carry
+        flat = jnp.argmin(d)
+        i, j = flat // n, flat % n
+        dij = d[i, j]
+        ni, nj = sizes[i], sizes[j]
+        di, dj = d[i], d[j]
+        if linkage == "ward":
+            new = ((ni + sizes) * di + (nj + sizes) * dj
+                   - sizes * dij) / (ni + nj + sizes)
+        elif linkage == "average":
+            new = (ni * di + nj * dj) / (ni + nj)
+        elif linkage == "complete":
+            new = jnp.maximum(di, dj)
+        else:
+            new = jnp.minimum(di, dj)
+        new = new.at[i].set(jnp.inf).at[j].set(jnp.inf)
+        d = d.at[i, :].set(new).at[:, i].set(new)
+        d = d.at[j, :].set(jnp.inf).at[:, j].set(jnp.inf)
+        sizes = sizes.at[i].set(ni + nj).at[j].set(0.0)
+        labels = jnp.where(labels == j, i, labels)
+        return d, sizes, labels
+
+    _, _, labels = jax.lax.fori_loop(
+        0, n - num_clusters, body,
+        (d, jnp.ones(n, jnp.float32), jnp.arange(n)))
+    reps = jnp.unique(labels, size=num_clusters)
+    return np.asarray(jnp.searchsorted(reps, labels))
+
+
+def _distances(rng, n, kind):
+    """``points``: distances of Gaussian points; ``ties``: integer-
+    rounded distances of a small integer grid with every point
+    duplicated, so many pairs tie exactly."""
+    if kind == "points":
+        pts = rng.normal(size=(n, 3))
+    else:
+        pts = rng.integers(0, 5, size=(n, 2)).astype(float)
+        pts[n // 2:] = pts[:n - n // 2]
     d = np.linalg.norm(pts[:, None] - pts[None, :], axis=-1)
-    for m in (2, 4, 9):
+    return np.round(d) if kind == "ties" else d
+
+
+def _eq9_like(rng, n, c=10):
+    """Angles between Dirichlet(0.05) vectors, as Eq. 9 builds them."""
+    p = rng.dirichlet(np.full(c, 0.05), size=n)
+    p /= np.linalg.norm(p, axis=1, keepdims=True)
+    return np.arccos(np.clip(p @ p.T, -1.0, 1.0)).astype(np.float32)
+
+
+_MERGE_CASES = (
+    [pytest.param(lk, 18, "points", id=lk) for lk in LINKAGES]
+    + [pytest.param(lk, n, kind, id=f"{lk}-{n}-{kind}")
+       for lk in LINKAGES for n, kind in ((18, "ties"), (257, "points"),
+                                          (257, "ties"))])
+
+
+@pytest.mark.parametrize("linkage,n,kind", _MERGE_CASES)
+def test_agglomerate_device_matches_numpy(linkage, n, kind, rng):
+    """Bit-identical labels against the numpy twin and against the
+    whole-matrix argmin oracle: the same merge order, ties included."""
+    d = _distances(rng, n, kind)
+    for m in (1, 2, 4, 9, n):
         a = agglomerate(d, m, linkage=linkage)
         b = np.asarray(agglomerate_device(jnp.asarray(d), m,
                                           linkage=linkage))
         np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(_agglomerate_flat(d, m, linkage), b)
+
+
+def test_agglomerate_device_in_scan_and_vmap(rng):
+    """Under ``jit`` inside ``lax.scan`` and under ``vmap`` over three
+    matrices (the repair loop then runs until the slowest is done) the
+    labels and repair counts equal the one-matrix calls."""
+    ds = jnp.asarray(np.stack([_eq9_like(rng, 40) for _ in range(2)]
+                              + [_distances(rng, 40, "ties")]),
+                     jnp.float32)
+    m = 5
+    one = [_agglomerate_device(d, m) for d in ds]
+
+    @jax.jit
+    def scanned(ds):
+        return jax.lax.scan(
+            lambda c, d: (c, _agglomerate_device(d, m)), 0, ds)[1]
+
+    for labels, repairs in (scanned(ds),
+                            jax.vmap(lambda d: _agglomerate_device(d, m))(
+                                ds)):
+        for s, (lab, rep) in enumerate(one):
+            np.testing.assert_array_equal(np.asarray(labels[s]),
+                                          np.asarray(lab))
+            np.testing.assert_array_equal(
+                np.asarray(labels[s]), _agglomerate_flat(ds[s], m))
+            assert int(repairs[s]) == int(rep)
 
 
 def test_cluster_means_device_matches_numpy(rng):

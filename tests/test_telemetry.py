@@ -134,6 +134,57 @@ def test_fairness_counts_accumulate():
     assert 0.0 < float(tel["fairness/eff_participation"]) <= 1.0
 
 
+def _ward_repairs(d, m):
+    """Stale-row repairs of a numpy walk of Ward's merge loop with a
+    lower-bound row-minimum cache (``core.clustering.agglomerate``)."""
+    d = np.array(d, float) ** 2
+    n = len(d)
+    np.fill_diagonal(d, np.inf)
+    sizes, row_min, repairs = np.ones(n), d.min(axis=1), 0
+    for _ in range(n - m):
+        while True:
+            i = int(np.argmin(row_min))
+            j = int(np.argmin(d[i]))
+            if d[i, j] == row_min[i]:
+                break
+            row_min[i] = d[i, j]
+            repairs += 1
+        i, j = min(i, j), max(i, j)
+        ni, nj = sizes[i], sizes[j]
+        new = ((ni + sizes) * d[i] + (nj + sizes) * d[j]
+               - sizes * d[i, j]) / (ni + nj + sizes)
+        new[i] = new[j] = np.inf
+        d[i], d[:, i], d[:, j] = new, new, np.inf
+        sizes[i], sizes[j] = ni + nj, 0.0
+        np.minimum(row_min, new, out=row_min)
+        row_min[i], row_min[j] = new.min(), np.inf
+    return repairs
+
+
+def test_selection_cluster_repairs_counter():
+    """``selection/cluster_repairs`` counts the stale-row repairs of one
+    clustering of the cached distance, as a numpy walk counts them."""
+    from repro.core import make_functional
+    pts = np.array([0.0, 2.0, 3.0, 7.0, 8.0, 13.0])
+    d = np.abs(pts[:, None] - pts[None, :])
+    fn = make_functional("hics", num_clients=6, num_select=2,
+                         total_rounds=4)
+    state = fn.init(jax.random.PRNGKey(0))
+    state = state._replace(dist_cache=jax.numpy.asarray(d,
+                                                        jax.numpy.float32))
+    m = make_metrics(MetricsSpec(("selection",)), fn=fn, num_clients=6,
+                     num_select=2)
+    _, tel = m.step(m.init(), TelemetryCtx(ids=np.array([0, 1]),
+                                           state=state))
+    rep = tel["selection/cluster_repairs"]
+    assert rep.shape == () and np.issubdtype(rep.dtype, np.integer)
+    assert int(rep) >= 0
+    assert int(rep) == _ward_repairs(d, 2) == 2
+    off_m = make_metrics(MetricsSpec())
+    _, off = off_m.step(off_m.init(), TelemetryCtx())
+    assert off["selection/cluster_repairs"].shape == (0,)
+
+
 # ---------------------------------------------------------------------------
 # invariance: telemetry never perturbs the run (the core guarantee)
 # ---------------------------------------------------------------------------

@@ -5,11 +5,13 @@ compiler refuses: a primitive Mosaic cannot lower, a misaligned tile, a
 VMEM overrun.  These tests compile each kernel with ``interpret=False``
 for one chip of a described ``v5e:2x2`` topology — no chip is attached
 and nothing runs — and check that the compiled program holds the kernel
-(``tpu_custom_call``).  The topology is described inside a fixture, so
-a worker that cannot describe one skips these tests, and no other
-module or worker loads the TPU compiler.
+(``tpu_custom_call``); the Ward merge loop, that it updates its
+distance matrix in place with no whole-matrix copy.  The topology is
+described inside a fixture, so a worker that cannot describe one skips
+these tests, and no other module or worker loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -99,3 +101,48 @@ def test_kernel_compiles_for_v5e(one_chip, case, c):
             for s in shapes(c)]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _loop_ops(hlo: str, shape: str) -> set:
+    """Kinds of the ops producing ``shape`` inside every while loop's
+    body and condition (and what they call) of an optimized HLO text."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            comps[name].append(line)
+    called = re.compile(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)")
+    todo = [c for lines in comps.values() for ln in lines
+            if " while(" in ln for c in called.findall(ln)]
+    seen, kinds = set(), set()
+    op = re.compile(r"= %s\{[^}]*\} ([a-z\-]+)\(" % re.escape(shape))
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for ln in comps[c]:
+            todo += called.findall(ln)
+            m = op.search(ln)
+            if m:
+                kinds.add(m.group(1))
+    return kinds
+
+
+def test_ward_merge_loop_is_copy_free_for_v5e(one_chip):
+    """At the cross-device cell's N, the only op on the (N, N) matrix
+    inside the merge loop is the in-place row update: no copy, no
+    relayout, no whole-matrix reduction."""
+    from repro.core.clustering import agglomerate_device
+    n = 2000
+    d = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=one_chip)
+    hlo = jax.jit(lambda d: agglomerate_device(d, 10, precomputed=True)
+                  ).lower(d).compile().as_text()
+    kinds = _loop_ops(hlo, f"f32[{n},{n}]")
+    assert kinds - {"parameter", "get-tuple-element", "tuple"} == {
+        "dynamic-update-slice"}

@@ -1,6 +1,8 @@
 """Plain reference of the federated job the program runs: HiCS-FL
 (Algorithm 1) with FedAvg clients, written from the paper and the
-configuration file and importing nothing of the program.
+configuration file and importing nothing of the program.  The model is
+the configuration's family (``families/<family>.py``): its
+``init_params``, ``loss`` and ``head_update``.
 
 One round t of a job of T rounds, from the global parameters θ, the
 server's Δb buffer (N, C) and the set of clients seen so far:
@@ -16,10 +18,18 @@ server's Δb buffer (N, C) and the set of clients seen so far:
    γ₀(1 − t/T), then a client by Gumbel argmax of log p_k inside it.
 2. Local update of each selected client: ``epochs`` passes of
    mini-batch SGD over a fresh permutation of its padded rows (the
-   first ⌊cap/B⌋·B), masked mean cross-entropy, a batch with no real
-   row leaves the parameters as they are; step size lr·0.5^⌊t/10⌋.
+   first ⌊cap/B⌋·B), the family's masked mean loss, a batch with no
+   real row leaves the parameters as they are; step size
+   lr·0.5^⌊t/10⌋.
 3. Aggregation θ ← mean of the K local parameters; Δb rows of the K
-   clients ← b_k − b (the output layer's bias update).
+   clients ← the family's head update (a classifier's: b_k − b, the
+   output layer's bias update).
+
+Steps 2 and 3 scan over blocks of the cohort, vmapped inside a block,
+and the mean is the blocks' running sum over K.  A block is the whole
+cohort unless the job sets ``cohort_block``: a reference at published
+widths then holds one block of parameter copies, not K.  Only the order
+of that sum differs.
 
 The keys follow one chain, as the program's scanned driver draws them:
 ``rng, k0 = split(PRNGKey(seed))`` initialises θ, then each round
@@ -33,19 +43,18 @@ the Δb and seen set the previous call left, and at rounds t > 0 inside
 a call, from the state the program's segments left before them
 (``select_at``), where γ_t has moved off γ₀.
 Everything runs under ``jax.default_matmul_precision("highest")`` in
-``dtype`` (float32; bfloat16 is the control).
+``dtype`` (float32; bfloat16 is the control): the parameters, and the
+rows where they are floating (token ids stay integers).
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from benchlib import layers
 
 #: HiCS-FL's selector constants as the program's selector defaults
 #: state them: softmax temperature τ, Eq. 9's λ, the annealing γ₀.
@@ -61,6 +70,7 @@ NORM_EPS = 1e-8
 class Job:
     """What a round needs besides the state: static sizes and data."""
     cfg: dict                  # the configuration file
+    family: Any                # the configuration's family module
     num_clients: int
     num_select: int
     job_rounds: int
@@ -68,6 +78,18 @@ class Job:
     epochs: int
     batch_size: int
     dtype: Any = jnp.float32
+    #: clients a block of the cohort trains at once; None: all K
+    cohort_block: Optional[int] = None
+
+    def __post_init__(self):
+        b = self.block
+        if b < 1 or self.num_select % b:
+            raise ValueError(f"reference_cohort_block {b} does not divide "
+                             f"the cohort of {self.num_select}")
+
+    @property
+    def block(self) -> int:
+        return self.cohort_block or self.num_select
 
 
 def entropy(v, temperature=TEMPERATURE):
@@ -161,15 +183,6 @@ def coverage_sample(key, seen, k: int):
     return jax.lax.top_k(g + jnp.where(seen, 0.0, 1e6), k)[1]
 
 
-def _masked_ce(logits, y, m):
-    logits = logits.astype(jnp.float32)
-    nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
-        logits, y[:, None], axis=-1)[:, 0]
-    m = m.astype(jnp.float32)
-    return jnp.sum(jnp.where(m > 0, nll, 0.0) * m) / jnp.maximum(
-        jnp.sum(m), 1.0)
-
-
 def local_sgd(job: Job, params, x, y, m, key, lr_scale):
     """One client's local epochs; returns (params, mean batch loss)."""
     s = x.shape[0]
@@ -179,13 +192,14 @@ def local_sgd(job: Job, params, x, y, m, key, lr_scale):
     step_size = (job.lr * lr_scale).astype(job.dtype)
 
     def loss_fn(p, xb, yb, mb):
-        return _masked_ce(layers.forward(job.cfg, p, xb), yb, mb)
+        return job.family.loss(job.cfg, p, xb, yb, mb)
+
+    def batches(a, perm):
+        return a[perm].reshape(nb, bs, *a.shape[1:])
 
     def epoch(p, ekey):
         perm = jax.random.permutation(ekey, s)[:used]
-        xb = x[perm].reshape(nb, bs, x.shape[-1])
-        yb = y[perm].reshape(nb, bs)
-        mb = m[perm].reshape(nb, bs)
+        xb, yb, mb = batches(x, perm), batches(y, perm), batches(m, perm)
 
         def step(p, batch):
             xi, yi, mi = batch
@@ -228,11 +242,23 @@ def _round(job: Job, x, y, m, carry, inp):
     keys = jax.random.split(k_loc, job.num_select)
     local = jax.vmap(lambda xi, yi, mi, ki: local_sgd(
         job, params, xi, yi, mi, ki, lr_scale))
-    new, losses = local(x[ids], y[ids], m[ids], keys)
-    head = job.cfg["layers"][-1]["name"]
-    db = (new[head]["b"] - params[head]["b"][None]).astype(delta_b.dtype)
-    params = jax.tree_util.tree_map(lambda a: jnp.mean(a, axis=0), new)
-    delta_b = delta_b.at[ids].set(db)
+    head_update = functools.partial(job.family.head_update, job.cfg)
+
+    def block(total, inp):
+        bids, bkeys = inp
+        new, losses = local(x[bids], y[bids], m[bids], bkeys)
+        total = jax.tree_util.tree_map(
+            lambda s, a: s + jnp.sum(a, axis=0), total, new)
+        return total, (head_update(new, params), losses)
+
+    nblk = job.num_select // job.block
+    total, (db, losses) = jax.lax.scan(
+        block, jax.tree_util.tree_map(jnp.zeros_like, params),
+        (ids.reshape(nblk, -1), keys.reshape(nblk, -1, *keys.shape[1:])))
+    db = db.reshape(job.num_select, *db.shape[2:])
+    losses = losses.reshape(-1)
+    params = jax.tree_util.tree_map(lambda s: s / job.num_select, total)
+    delta_b = delta_b.at[ids].set(db.astype(delta_b.dtype))
     out = (jnp.mean(losses.astype(jnp.float32)), entropy(delta_b))
     return (params, delta_b, rng), out
 
@@ -264,7 +290,7 @@ def start(job: Job, seed: int, num_classes: int):
     """The reference's state before the job's first round."""
     rng, k0 = jax.random.split(jax.random.PRNGKey(seed))
     params = jax.tree_util.tree_map(
-        lambda a: a.astype(job.dtype), layers.init_params(job.cfg, k0))
+        lambda a: a.astype(job.dtype), job.family.init_params(job.cfg, k0))
     return (params, jnp.zeros((job.num_clients, num_classes), job.dtype),
             rng)
 
@@ -274,7 +300,8 @@ def follow_call(job: Job, x, y, m, carry, ids, mid=()):
     (job_rounds, K) cohorts.  Returns (carry, per-round loss, Ĥ of every
     client after each round, {t: Δb before round t} for each t in
     ``mid``)."""
-    x = x.astype(job.dtype)
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        x = x.astype(job.dtype)
     ids = jnp.asarray(ids, jnp.int32)
     cuts = [0, *sorted(mid), ids.shape[0]]
     losses, ents, mid_db = [], [], {}

@@ -17,6 +17,15 @@ branch or call that runs its computation; a fusion without metadata
 takes its fused root's.  Operation names are unique only within one
 program, so time is counted only inside the ``jit_scan_segment``
 program's intervals on the device.
+
+A loop, branch or call (``tracefile.CONTAINERS``) is an event of its own
+on the device's operations line, and holds the events of what it runs.
+Between those the device is still busy with the container itself (a
+loop's trip: its condition, the hand-over to the next iteration), so
+each event counts its own time, its length less that of the events
+directly inside it, under its own scope.  A loop of many small
+iterations spends much of its time so: Ward's merge loop at N = 2000
+about half.
 """
 from __future__ import annotations
 
@@ -24,7 +33,7 @@ import bisect
 import re
 from typing import Dict, List, Optional, Tuple
 
-from benchlib.tracefile import CONTAINERS
+from benchlib.tracefile import Event
 
 PROGRAM = "scan_segment"
 MODULE = "jit_scan_segment"
@@ -107,15 +116,14 @@ def _parse(text: str) -> Tuple[Dict[str, List[_Instr]], str]:
     return comps, entry
 
 
-def op_scopes(text: str) -> Dict[str, Optional[str]]:
+def op_scopes(text: str) -> Dict[str, str]:
     """{instruction name: scope path} for every instruction of the
-    program that runs on the device as an operation of its own: those
-    of the entry computation and of the loops, branches and calls under
-    it, not those inside fusions.  Path "" is unscoped; a container's
-    (``tracefile.CONTAINERS``) is None, as its time is that of the
-    operations it runs."""
+    program that runs on the device as an operation or a container of
+    its own: those of the entry computation and of the loops, branches
+    and calls under it, not those inside fusions.  Path "" is
+    unscoped."""
     comps, entry = _parse(text)
-    out: Dict[str, Optional[str]] = {}
+    out: Dict[str, str] = {}
     seen = set()
 
     def walk(comp: str, inherited: str) -> None:
@@ -125,8 +133,7 @@ def op_scopes(text: str) -> Dict[str, Optional[str]]:
         for ins in comps[comp]:
             path = scope_path(ins.op_name) or inherited
             if ins.opcode not in FREE:
-                out[ins.name] = (None if ins.opcode in CONTAINERS
-                                 else path)
+                out[ins.name] = path
             for callee in ins.callees:
                 walk(callee, path)
 
@@ -134,12 +141,29 @@ def op_scopes(text: str) -> Dict[str, Optional[str]]:
     return out
 
 
-def scope_seconds(trace, scopes: Dict[str, Optional[str]],
+def self_times(events) -> List[Tuple[object, float]]:
+    """(event, its own time) for the events of one device line, which
+    nest: an event's length less that of the events directly inside
+    it.  The own times sum to the union of the events' intervals."""
+    out: List[list] = []
+    stack: List[int] = []
+    for e in sorted(events, key=lambda e: (e.start, -e.dur)):
+        while stack and out[stack[-1]][0].end <= e.start:
+            stack.pop()
+        if stack:
+            parent = out[stack[-1]]
+            parent[1] -= min(e.end, parent[0].end) - e.start
+        out.append([e, e.dur])
+        stack.append(len(out) - 1)
+    return [(e, t) for e, t in out]
+
+
+def scope_seconds(trace, scopes: Dict[str, str],
                   window: Tuple[float, float]) -> Dict[str, float]:
-    """{scope path: device seconds} of the operations that ran inside
-    the ``jit_scan_segment`` program's intervals in ``window``, averaged
-    over devices.  Containers are left out; a name the table lacks
-    counts as unscoped."""
+    """{scope path: device seconds} of the events that ran inside the
+    ``jit_scan_segment`` program's intervals in ``window``, each its own
+    time (``self_times``), averaged over devices.  A name the table
+    lacks counts as unscoped."""
     lo, hi = window
     totals: Dict[str, float] = {}
     n_dev = max(1, len(trace.ops))
@@ -148,21 +172,21 @@ def scope_seconds(trace, scopes: Dict[str, Optional[str]],
                       if (e.name == MODULE or e.name.startswith(MODULE + "("))
                       and e.start + e.dur > lo and e.start < hi)
         starts = [a for a, _ in mods]
+        inside = []
         for e in ops:
             i = bisect.bisect_right(starts, e.start) - 1
             if i < 0 or e.start + e.dur > mods[i][1]:
                 continue
             a, b = max(e.start, lo), min(e.start + e.dur, hi)
-            if b <= a:
-                continue
+            if b > a:
+                inside.append(Event(e.name, a, b - a))
+        for e, t in self_times(inside):
             path = scopes.get(e.name, "")
-            if path is None:
-                continue
-            totals[path] = totals.get(path, 0.0) + (b - a)
+            totals[path] = totals.get(path, 0.0) + t
     return {k: v * 1e-9 / n_dev for k, v in totals.items()}
 
 
-def program_scopes() -> Optional[Dict[str, Optional[str]]]:
+def program_scopes() -> Optional[Dict[str, str]]:
     """The scanned round's scope table from the program, or None where
     the program keeps no text (untraced, or a program without it)."""
     try:
@@ -173,19 +197,34 @@ def program_scopes() -> Optional[Dict[str, Optional[str]]]:
     return op_scopes(text) if text else None
 
 
-def round_device_ms(rec: dict, top: str) -> Optional[float]:
-    """Device ms per round of the operations under top-level scope
-    ``top`` in the traced call; None without the program's scope table
-    or its program in the trace.  The scope times are kept in ``rec``
-    for the next reader."""
+def _scope_times(rec: dict) -> Optional[Dict[str, float]]:
+    """The traced call's device seconds by scope path, kept in ``rec``
+    for the next reader; None without the program's scope table."""
     if "scope_s" not in rec:
         scopes = program_scopes()
         tr = rec["trace"]
         rec["scope_s"] = (None if scopes is None
                           else scope_seconds(tr, scopes, tr.window))
-    times, rounds = rec["scope_s"], len(rec["ids"])
+    return rec["scope_s"]
+
+
+def round_device_ms(rec: dict, top: str) -> Optional[float]:
+    """Device ms per round of the operations under top-level scope
+    ``top`` in the traced call; None without the program's scope table
+    or its program in the trace."""
+    times, rounds = _scope_times(rec), len(rec["ids"])
     if not times or rounds == 0:
         return None
     t = sum(s for path, s in times.items()
             if path == top or path.startswith(top + "/"))
     return t / rounds * 1e3
+
+
+def unscoped_share(rec: dict) -> Optional[float]:
+    """Percent of the scanned program's device time in the traced call
+    that falls under no named scope; None as ``round_device_ms``."""
+    times = _scope_times(rec)
+    total = sum(times.values()) if times else 0.0
+    if total <= 0.0:
+        return None
+    return 100.0 * times.get("", 0.0) / total

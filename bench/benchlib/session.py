@@ -2,7 +2,8 @@
 stretch), then the comparison with the reference.
 
 Timed path: the program's scanned federated driver as a user drives it,
-``FederatedServer(..., FedConfig(jit_rounds=True))`` and successive
+``FederatedServer(..., FedConfig(jit_rounds=True))`` over the model and
+the data of the configuration's family (``catalog.family``), and successive
 ``server.run()`` calls, each a job of ``job_rounds`` rounds that goes on
 from where the last one stopped (parameters, selector state and the
 distance cache carry over).  Set-up builds the cell's data from the
@@ -40,11 +41,9 @@ import numpy as np
 
 from benchlib import catalog, compare, peaks, reference, traffic
 from benchlib import tracefile
-from repro.configs import get_config
 from repro.fed.client import LocalSpec
 from repro.fed.server import FedConfig, FederatedServer
 from repro.launch.cache import enable_compile_cache
-from repro.models.classifier import make_classifier
 
 #: calls of the live selector's jitted ``select`` the traced run makes
 SELECT_CALLS = 20
@@ -92,11 +91,10 @@ class Job:
     def __init__(self, cell: catalog.Cell, seed: int,
                  selector_kw: Optional[dict] = None):
         wl = cell.workload
+        family = catalog.family(cell.config)
         self.prog_seed = traffic.split_seed(seed)[1]
-        self.data = traffic.make_traffic(wl, seed)
-        model = get_config(cell.config["registry"])
-        init, apply, _ = make_classifier(
-            model, input_dim=int(wl["data"]["dim"]))
+        self.data = family.make_traffic(wl, seed)
+        init, apply = family.program_model(cell.config, wl)
         loc = wl["local"]
         self.fed_cfg = FedConfig(
             num_clients=int(wl["num_clients"]),
@@ -191,13 +189,16 @@ def follow(cell: catalog.Cell, job_seed: int, data: traffic.Traffic,
     """The reference over the followed calls; returns (refs, θ₀, the
     reference's job, the clients' weights)."""
     wl = cell.workload
+    block = wl.get("reference_cohort_block")
     rj = reference.Job(
-        cfg=cell.config, num_clients=int(wl["num_clients"]),
+        cfg=cell.config, family=catalog.family(cell.config),
+        num_clients=int(wl["num_clients"]),
         num_select=int(wl["num_select"]),
         job_rounds=int(wl["job_rounds"]), lr=float(wl["local"]["lr"]),
         epochs=int(wl["local"]["epochs"]),
         batch_size=int(wl["local"]["batch_size"]),
-        dtype=dtype or jnp.float32)
+        dtype=dtype or jnp.float32,
+        cohort_block=None if block is None else int(block))
     carry = reference.start(rj, job_seed, int(cell.config["num_classes"]))
     p0 = reference.to_host(carry[0])
     w = data.sizes.astype(np.float64)

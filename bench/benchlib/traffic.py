@@ -1,45 +1,54 @@
-"""The general traffic generator: a cell's federated data from its
-workload file and ``--seed``.
+"""What every family's traffic shares: a client population with
+non-IID labels, drawn from a cell's workload file and ``--seed``.
 
-A cell's traffic is a client population with non-IID labels, drawn as
-the HiCS-FL paper (§4.1, App. A.10) draws it: the clients are split
-into equal groups, one per concentration parameter α, each group holds
-an equal share of the training samples, and within a group each
-class's samples are split over the group's clients by Dir(α)
-proportions.  Clients left with fewer than ``min_per_client`` samples
-take them from the group's largest client.
+The population is drawn as the HiCS-FL paper (§4.1, App. A.10) draws
+it: the clients are split into equal groups, one per concentration
+parameter α, each group holds an equal share of the training samples,
+and within a group each class's samples are split over the group's
+clients by Dir(α) proportions.  Clients left with fewer than
+``min_per_client`` samples take them from the group's largest client.
+A family whose rows carry no class (a language model's) reads the
+classes as topics.
 
 The split (how many samples of which class each client holds) is drawn
 once from the workload's fixed ``structure_seed``, so every ``--seed``
 gives the same set of client sizes and the same padded capacity: the
 same work and the same compiled shapes.  ``--seed`` permutes which
-client holds which share, relabels the classes, and draws the samples
-themselves: a Gaussian mixture with one unit prototype per class (scaled
-by ``proto_scale``), a rank-``rank`` within-class subspace and isotropic
-noise, the task the program's own synthetic generator describes.
-
-The samples are made on the device in one jitted call; the host builds
+client holds which share, relabels the classes, and gives the key from
+which the family draws the rows themselves (``families/<family>.py``,
+``make_traffic``), on the device in one jitted call; the host builds
 only the (N, cap) index layout.
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Dict, List, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
 class Traffic:
-    """One cell's data, on the device (jax arrays) plus host counts."""
-    x: object            # (N, cap, dim) f32, zero rows past a client's size
-    y: object            # (N, cap) i32, 0 past a client's size
-    mask: object         # (N, cap) f32, 1 on real rows
-    test: Dict[str, object]   # {"x": (T, dim), "y": (T,), "mask": (T,)}
+    """One cell's data, on the device (jax arrays) plus host counts.
+    Rows are whatever the family trains on, of any dtype: a classifier's
+    (dim,) float features, a language model's int32 token ids."""
+    x: object            # (N, cap, ...) rows, zero past a client's size
+    y: object            # (N, cap, ...) i32 targets, 0 past a client's size
+    mask: object         # (N, cap, ...) f32, 1 on real rows (or tokens)
+    test: Dict[str, object]   # {"x": (T, ...), "y": (T, ...), "mask": ...}
     sizes: np.ndarray    # (N,) real rows per client
+
+
+@dataclasses.dataclass(frozen=True)
+class Split:
+    """A cell's client population for one seed, on the host: the label
+    of every training row and where each client's rows sit."""
+    sizes: np.ndarray    # (N,) real rows per client
+    y_flat: np.ndarray   # (total,) i32 labels of all rows, client by client
+    idx: np.ndarray      # (N, cap) i32 row of y_flat; ``total`` past a size
+    y_test: np.ndarray   # (samples_test,) i32 labels of the test set
+    key: object          # the jax key the family draws the rows from
 
 
 def split_seed(seed: int) -> tuple:
@@ -104,10 +113,10 @@ def _labels(rng: np.random.Generator, counts: np.ndarray) -> List[np.ndarray]:
     return out
 
 
-def make_traffic(wl: dict, seed: int) -> Traffic:
-    """The cell's client data and test set for ``seed``."""
-    data = wl["data"]
-    n, c = int(wl["num_clients"]), int(data["num_classes"])
+def split(wl: dict, seed: int, num_classes: int) -> Split:
+    """The cell's client population for ``seed``, over ``num_classes``
+    classes (or topics)."""
+    n, c = int(wl["num_clients"]), int(num_classes)
     base = partition_counts(int(wl["structure_seed"]), n, c,
                             int(wl["samples_train"]), wl["alphas"],
                             int(wl.get("min_per_client", 2)))
@@ -116,50 +125,13 @@ def make_traffic(wl: dict, seed: int) -> Traffic:
     counts = base[rng.permutation(n)][:, rng.permutation(c)]
     sizes = counts.sum(axis=1)
     cap = int(sizes.max())
-    ys = _labels(rng, counts)
-    y_flat = np.concatenate(ys).astype(np.int32)
+    y_flat = np.concatenate(_labels(rng, counts)).astype(np.int32)
     total = y_flat.shape[0]
     offsets = np.concatenate([[0], np.cumsum(sizes)[:-1]])
     pos = np.arange(cap)[None, :]
     idx = np.where(pos < sizes[:, None], offsets[:, None] + pos,
                    total).astype(np.int32)          # `total` = zero row
-    t = int(wl["samples_test"])
-    y_test = rng.integers(0, c, size=t).astype(np.int32)
-    x, y, m, xt = _draw(jax.random.PRNGKey(jax_seed), jnp.asarray(y_flat),
-                        jnp.asarray(idx), jnp.asarray(y_test),
-                        int(data["dim"]), int(data["rank"]),
-                        float(data["noise"]), float(data["proto_scale"]), c)
-    test = {"x": xt, "y": jnp.asarray(y_test),
-            "mask": jnp.ones((t,), jnp.float32)}
-    return Traffic(x=x, y=y, mask=m, test=test, sizes=sizes)
-
-
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
-def _draw(key, y_flat, idx, y_test, dim, rank, noise, proto_scale,
-          num_classes):
-    """Samples of every client (gathered into the (N, cap) layout, the
-    index ``len(y_flat)`` giving a zero row) and of the test set."""
-    kp, kb, kc, kn, kct, knt = jax.random.split(key, 6)
-    protos = jax.random.normal(kp, (num_classes, dim), jnp.float32)
-    protos = proto_scale * protos / jnp.linalg.norm(protos, axis=1,
-                                                    keepdims=True)
-    # (C·rank, dim): row block c is class c's within-class basis
-    bases = jax.random.normal(kb, (num_classes * rank, dim),
-                              jnp.float32) / np.sqrt(dim)
-
-    def samples(y, kcoef, knoise):
-        coef = jax.random.normal(kcoef, (y.shape[0], rank), jnp.float32)
-        onehot = jax.nn.one_hot(y, num_classes, dtype=jnp.float32)
-        low = (onehot[:, :, None] * coef[:, None, :]).reshape(
-            y.shape[0], num_classes * rank)
-        return (protos[y] + jnp.dot(low, bases,
-                                    precision=jax.lax.Precision.HIGHEST)
-                + noise * jax.random.normal(knoise, (y.shape[0], dim),
-                                            jnp.float32))
-
-    x_flat = samples(y_flat, kc, kn)
-    x_flat = jnp.concatenate([x_flat, jnp.zeros((1, dim), jnp.float32)])
-    y_pad = jnp.concatenate([y_flat, jnp.zeros((1,), jnp.int32)])
-    live = idx < y_flat.shape[0]
-    return (x_flat[idx], y_pad[idx], live.astype(jnp.float32),
-            samples(y_test, kct, knt))
+    y_test = rng.integers(0, c, size=int(wl["samples_test"])).astype(
+        np.int32)
+    return Split(sizes=sizes, y_flat=y_flat, idx=idx, y_test=y_test,
+                 key=jax.random.PRNGKey(jax_seed))

@@ -1,14 +1,15 @@
 """step.mfu: the FLOPs local training requires in the traced call, over
 the call's length times the chip's bf16 peak.
 
-Required FLOPs: forward and backward (6 per multiply-accumulate of the
-configuration's layers) of every real row a local step trains on, the
-expected count of ``local.useful_row_share``.  Padding rows, selection,
+Required FLOPs: forward and backward of every real row a local step
+trains on, the expected count of ``local.useful_row_share``, each
+costing the family's ``train_flops_per_row`` (a classifier's: 6 per
+multiply-accumulate of its layers).  Padding rows, selection,
 aggregation and evaluation do not count.
 """
 import numpy as np
 
-from benchlib import layers, peaks
+from benchlib import catalog, peaks
 
 
 def read(rec):
@@ -23,6 +24,7 @@ def read(rec):
     used = max(1, cap // bs) * bs
     rows = float(np.sum(np.asarray(rec["sizes"])[ids])) * used / cap \
         * int(loc["epochs"])
-    flops = rows * layers.train_flops_per_sample(cell.config)
+    flops = rows * catalog.family(cell.config).train_flops_per_row(
+        cell.config, cell.workload)
     pk = peaks.peak(rec["device_kind"])
     return 100.0 * flops / (tr.window_s * pk["bf16_flops"])

@@ -30,7 +30,7 @@ def test_cell_loads_with_its_metrics(name):
     assert cell.config["name"] == cell.entry["config"]
     assert {m["name"] for m in cell.end_to_end} == {
         "updates_per_s", "peak_hbm_mb", "setup_s"}
-    assert len(cell.per_layer) == 6
+    assert len(cell.per_layer) == 11
     assert set(cell.workload["limits"]) == {
         "loss", "update", "delta_b", "entropy", "distance", "selection"}
 

@@ -108,8 +108,8 @@ def test_op_scopes_of_a_scanned_round():
     table = scopes.op_scopes(HLO)
     assert table == {
         "copy.8": "",                         # before the loop
-        "while.9": None,                      # containers
-        "conditional.5": None, "while.4": None,
+        "while.9": "",                        # containers, by their own
+        "conditional.5": "select", "while.4": "select/cluster",
         "iota.2": "select/sample",
         "compare.1": "select/cluster",
         "copy.3": "select/cluster",           # XLA's copy: its loop's
@@ -123,10 +123,11 @@ def test_op_scopes_of_a_scanned_round():
 def _trace():
     """Window 0..200; the scanned program runs twice, the last time over
     the window's end; the eval program between them reuses an op name."""
-    ops = [Event("while.9", 10, 50),          # container
+    ops = [Event("while.9", 10, 50),          # container: 11 of its own
            Event("copy.8", 10, 2),            # unscoped
            Event("iota.2", 12, 3),            # select/sample
-           Event("copy.3", 15, 10),           # select/cluster
+           Event("while.4", 15, 10),          # select/cluster: 3 its own
+           Event("copy.3", 15, 7),            # select/cluster
            Event("fusion.1", 30, 20),         # local
            Event("dynamic-update-slice.6", 50, 4),
            Event("fusion.1", 100, 30),        # jit_evaluate's own
@@ -146,9 +147,24 @@ def _trace():
 def test_scope_seconds_inside_the_scanned_program_only():
     got = scopes.scope_seconds(_trace(), scopes.op_scopes(HLO),
                                (0, 200))
-    assert got == pytest.approx({"": 6e-9, "select/sample": 3e-9,
+    assert got == pytest.approx({"": 17e-9, "select/sample": 3e-9,
                                  "select/cluster": 20e-9,
                                  "local": 20e-9})
+
+
+def test_self_times_of_nested_events():
+    """A loop's own time is its length less the events directly inside
+    it; the own times add up to the union of the intervals."""
+    loop, a, inner, b, after = (Event("while.1", 0, 100),
+                                Event("fusion.1", 10, 20),
+                                Event("while.2", 40, 30),
+                                Event("fusion.2", 45, 10),
+                                Event("copy.1", 120, 5))
+    got = {e.name: t for e, t in scopes.self_times(
+        [b, after, inner, a, loop])}
+    assert got == {"while.1": 50, "fusion.1": 20, "while.2": 20,
+                   "fusion.2": 10, "copy.1": 5}
+    assert sum(got.values()) == 105
 
 
 @pytest.fixture
@@ -166,13 +182,15 @@ def _rec(tr=None):
     ("local.round_device_ms", 20e-9 / 2 * 1e3),
     ("driver.history_ms_per_round", (10 + 10 + 5) * 1e-9 / 2 * 1e3),
     ("driver.eval_ms_per_round", 40e-9 / 2 * 1e3),
+    ("round.unscoped_share", 100 * 17 / (17 + 3 + 20 + 20)),
 ])
 def test_reader(program, metric, ms):
     assert catalog.reader(metric)(_rec()) == pytest.approx(ms)
 
 
 @pytest.mark.parametrize("metric", ["select.round_device_ms",
-                                    "local.round_device_ms"])
+                                    "local.round_device_ms",
+                                    "round.unscoped_share"])
 def test_device_readers_read_nothing_without_the_program_text(
         monkeypatch, metric):
     monkeypatch.setattr(program_trace, "_PROGRAMS", {})
@@ -180,7 +198,8 @@ def test_device_readers_read_nothing_without_the_program_text(
 
 
 @pytest.mark.parametrize("metric", ["select.round_device_ms",
-                                    "local.round_device_ms"])
+                                    "local.round_device_ms",
+                                    "round.unscoped_share"])
 def test_device_readers_read_nothing_without_the_scanned_program(
         program, metric):
     tr = _trace()

@@ -23,6 +23,12 @@ sits in a file of its own under ``bench/``, found by its name:
     ones; C is the configuration's ``num_classes``;
   - ``train_flops_per_row(cfg, wl)``: forward and backward of one row.
 
+  A family's ``loss`` may rematerialise each layer with
+  ``jax.checkpoint``: the same numbers, less activation memory.  A cell
+  whose parameters fill the chip's memory sets
+  ``reference_cohort_block: 1`` in its workload file, so the reference
+  trains one client of the cohort at a time.
+
 So a later change adds a cell, a configuration, a metric or a model
 family by adding files and entries, and edits none that is there.
 """

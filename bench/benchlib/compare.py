@@ -2,11 +2,13 @@
 on the timed path against the plain reference, as a handful of numbers,
 each held to the limit the cell's workload file states.
 
-The program's side of each followed call (``Record``) is read from
-the server after the call: the history of the call's rounds (ids,
+The program's side of each followed call (``Record``) is copied to
+the host as the call returns: the history of the call's rounds (ids,
 train loss, Ĥ of every client), the global parameters, and the
 selector's Δb buffer and distance cache.  The reference's side comes
-from ``reference.follow_call`` over the same calls.
+from ``reference.follow_call`` over the same calls.  Neither side keeps
+a parameter set: each keeps the norm of every leaf's change from θ₀
+(``change_norms``), taken one leaf at a time.
 
 Numbers (all ≥ 0, larger is worse):
 
@@ -35,7 +37,7 @@ Numbers (all ≥ 0, larger is worse):
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -48,7 +50,7 @@ class Record:
     ids: np.ndarray          # (R, K)
     loss: np.ndarray         # (R,)
     ent: np.ndarray          # (R, N)
-    params: Dict             # global params after the call
+    norms: List[float]       # ‖θ − θ₀‖ of each leaf after the call
     delta_b: np.ndarray      # (N, C)
     dist: np.ndarray         # (N, N) cache
     seen: np.ndarray         # (N,) bool: clients that have trained
@@ -63,30 +65,34 @@ class RefCall:
     """One followed call, reference side (host arrays)."""
     loss: np.ndarray
     ent: np.ndarray
-    params: Dict
+    norms: List[float]
     delta_b: np.ndarray
-    rng0: object             # the key chain's state before the call
-    rng: object              # ... and after it
+    rng0: np.ndarray         # the key chain's state before the call
     #: {t: Δb before round t} for the rounds the record's ``mid`` names
     mid_delta_b: Dict[int, np.ndarray] = dataclasses.field(
         default_factory=dict)
 
 
-def _leaves(tree) -> List[np.ndarray]:
+def _leaves(tree) -> list:
     if isinstance(tree, dict):
         return [x for k in sorted(tree) for x in _leaves(tree[k])]
-    return [np.asarray(tree, np.float64)]
+    return [tree]
 
 
-def update_gap(p_prog, p_ref, p0) -> float:
+def change_norms(params, p0) -> List[float]:
+    """‖θ − θ₀‖ of each leaf, in the leaves' sorted order, each in
+    float64 from its own copy: one leaf of either tree (host or device
+    arrays) is read and widened at a time."""
+    return [float(np.linalg.norm(np.asarray(a, np.float64)
+                                 - np.asarray(b, np.float64)))
+            for a, b in zip(_leaves(params), _leaves(p0))]
+
+
+def update_gap(prog: Sequence[float], ref: Sequence[float]) -> float:
     """Worst leaf's gap between the two norms of the parameters' change,
     over the larger of that leaf's and the median leaf's reference
     norm.  Leaves the reference moves by under a thousandth of the
     median leaf (a change that is round-off alone) are left out."""
-    prog = [np.linalg.norm(a - b) for a, b in zip(_leaves(p_prog),
-                                                 _leaves(p0))]
-    ref = [np.linalg.norm(a - b) for a, b in zip(_leaves(p_ref),
-                                                _leaves(p0))]
     floor = max(float(np.median(ref)), 1e-30)
     return max(abs(a - b) / max(b, floor) for a, b in zip(prog, ref)
                if b >= 1e-3 * floor)
@@ -101,8 +107,7 @@ def row_gap(prog: np.ndarray, ref: np.ndarray) -> float:
     return float(gaps.max())
 
 
-def numbers(records: List[Record], refs: List[RefCall], p0,
-            distance_fn,
+def numbers(records: List[Record], refs: List[RefCall], distance_fn,
             expected: List[Tuple[int, int, np.ndarray]]
             ) -> Dict[str, float]:
     """The six numbers over the followed calls; ``expected`` holds the
@@ -122,7 +127,7 @@ def numbers(records: List[Record], refs: List[RefCall], p0,
     return {
         "loss": float(np.max(np.abs(loss_p - loss_r)
                              / np.maximum(np.abs(loss_r), 1e-12))),
-        "update": max(update_gap(a.params, b.params, p0)
+        "update": max(update_gap(a.norms, b.norms)
                       for a, b in zip(records, refs)),
         "delta_b": max(row_gap(a.delta_b, b.delta_b)
                        for a, b in zip(records, refs)),

@@ -50,7 +50,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -263,8 +263,11 @@ def _round(job: Job, x, y, m, carry, inp):
     return (params, delta_b, rng), out
 
 
-@functools.partial(jax.jit, static_argnums=0)
+@functools.partial(jax.jit, static_argnums=0, donate_argnums=4)
 def _call(job: Job, x, y, m, carry, ts, ids):
+    """Rounds ``ts`` from ``carry``, which it donates: the caller's
+    carry is gone once the call is made, and the rounds' carry takes its
+    buffers, so no second copy of the parameters is held."""
     with jax.default_matmul_precision("highest"):
         return jax.lax.scan(functools.partial(_round, job, x, y, m), carry,
                             (ts, ids))
@@ -297,7 +300,8 @@ def start(job: Job, seed: int, num_classes: int):
 
 def follow_call(job: Job, x, y, m, carry, ids, mid=()):
     """One call of ``job_rounds`` rounds that trains the program's
-    (job_rounds, K) cohorts.  Returns (carry, per-round loss, Ĥ of every
+    (job_rounds, K) cohorts; ``carry`` is donated, so the caller keeps
+    the returned one alone.  Returns (carry, per-round loss, Ĥ of every
     client after each round, {t: Δb before round t} for each t in
     ``mid``)."""
     if jnp.issubdtype(x.dtype, jnp.floating):
@@ -314,11 +318,6 @@ def follow_call(job: Job, x, y, m, carry, ids, mid=()):
         losses.append(np.asarray(loss))
         ents.append(np.asarray(ent))
     return carry, np.concatenate(losses), np.concatenate(ents), mid_db
-
-
-def to_host(tree) -> Dict[str, Any]:
-    """A parameter pytree as float64 host arrays."""
-    return jax.tree_util.tree_map(lambda a: np.asarray(a, np.float64), tree)
 
 
 _DISTANCE = jax.jit(distance)

@@ -13,10 +13,16 @@ and finish the coverage sweep, so every timed round is a clustered one.
 The reference follows the run from the seed: every round of the calls
 up to and including the first that starts with a clustered round.  In
 that call the job also keeps the selector state that the program's
-segments left (a reference to the arrays,
-read from the server when it evaluates after the segment; no device
-read) before the rounds ``mid_rounds``, so selections inside the call,
-at t > 0, are checked too.
+segments left (host copies of Δb and the seen set, made when the server
+evaluates after the segment) before the rounds ``mid_rounds``, so
+selections inside the call, at t > 0, are checked too.
+
+A run holds no device array of the program's state past the call that
+made it: what the comparison reads of a followed call is copied to the
+host as the call returns (``Followed``), so a program that donates its
+carry to the next call loses nothing the comparison needs.  Neither
+side keeps a float64 parameter set: each keeps the norm of every leaf's
+change from θ₀, and θ₀ is kept once, as float32.
 
 Set-up ends with a full collection and ``gc.freeze()``: the objects
 set-up made (traced programs, the data's host copies) stay out of the
@@ -77,12 +83,25 @@ def device_entry(devs) -> dict:
 
 @dataclasses.dataclass
 class Followed:
-    """Device references to what one followed call left behind."""
-    h0: int
-    h1: int
-    params: object
-    state: object
-    mid: list = dataclasses.field(default_factory=list)  # [(t, (Δb, seen))]
+    """What one followed call left behind, copied to the host as it
+    returned (``mid`` as each of its segments did): numpy arrays alone."""
+    ids: np.ndarray          # (R, K)
+    loss: np.ndarray         # (R,)
+    ent: np.ndarray          # (R, N)
+    params: dict             # global parameters: float32 leaves
+    delta_b: np.ndarray      # (N, C)
+    dist: np.ndarray         # (N, N) cache
+    seen: np.ndarray         # (N,) bool
+    fresh: np.ndarray        # (N,) bool: seen, no refresh pending
+    mid: list                # [(t, Δb, seen)] before round t
+
+    def record(self, p0) -> compare.Record:
+        """The comparison's program side, each leaf's change from
+        ``p0`` (float32 host leaves) taken one leaf at a time."""
+        kept = {f.name: getattr(self, f.name)
+                for f in dataclasses.fields(self) if f.name != "params"}
+        return compare.Record(norms=compare.change_norms(self.params, p0),
+                              **kept)
 
 
 class Job:
@@ -137,17 +156,33 @@ class Job:
         finally:
             self.server.__dict__.pop("_eval_round", None)
         if self.following:
-            s = self.server
-            self.followed.append(Followed(h0, self.rounds_done, s.params,
-                                          s.selector.state, kept))
+            self.followed.append(self._host_copy(h0, kept))
             self.following = not starts_clustered
 
+    def _host_copy(self, h0: int, mid: list) -> Followed:
+        """The call that began at round ``h0`` as it left the server:
+        its history, and host copies of the parameters (leaf by leaf)
+        and of the selector's Δb, distance cache and seen set."""
+        s = self.server
+        h, st = s.history, s.selector.state
+        pending = np.zeros(self.fed_cfg.num_clients, bool)
+        if int(st.stale_fill):
+            pending[np.asarray(st.stale_ids)] = True
+        seen = np.array(st.seen)
+        return Followed(
+            ids=np.asarray(h["selected"][h0:], np.int64),
+            loss=np.asarray(h["train_loss"][h0:], np.float64),
+            ent=np.asarray(h["bias_entropy"][h0:], np.float64),
+            params=jax.tree_util.tree_map(np.array, s.params),
+            delta_b=np.asarray(st.delta_b, np.float64),
+            dist=np.array(st.dist_cache), seen=seen,
+            fresh=seen & ~pending, mid=mid)
+
     def _keep_states_before(self, rounds, kept: list) -> None:
-        """For the next call only: appends to ``kept`` (t, (Δb, seen))
-        as the segment that ends before each round t in ``rounds``
-        leaves them.  The server evaluates after every segment; the
-        wrapper adds one Python call to each and holds references,
-        nothing more."""
+        """For the next call only: appends to ``kept`` (t, Δb, seen),
+        host copies of the two as the segment that ends before each
+        round t in ``rounds`` leaves them.  The server evaluates after
+        every segment; the wrapper adds one Python call to each."""
         server = self.server
         evaluate = server._eval_round
 
@@ -155,39 +190,22 @@ class Job:
             evaluate(t, progress)
             if t + 1 in rounds:
                 st = server.selector.state
-                kept.append((t + 1, (st.delta_b, st.seen)))
+                kept.append((t + 1, np.asarray(st.delta_b, np.float64),
+                             np.array(st.seen)))
         server._eval_round = evaluate_and_keep
 
     def coverage_done(self) -> bool:
         return int(self.server.selector.state.unseen_count) == 0
 
-    def records(self) -> List[compare.Record]:
-        """Host copies of the program's side of each followed call."""
-        h = self.server.history
-        out = []
-        for f in self.followed:
-            st = f.state
-            pending = np.zeros(self.fed_cfg.num_clients, bool)
-            if int(st.stale_fill):
-                pending[np.asarray(st.stale_ids)] = True
-            mid = [(t, np.asarray(db, np.float64), np.asarray(seen))
-                   for t, (db, seen) in f.mid]
-            out.append(compare.Record(
-                ids=np.asarray(h["selected"][f.h0:f.h1], np.int64),
-                loss=np.asarray(h["train_loss"][f.h0:f.h1], np.float64),
-                ent=np.asarray(h["bias_entropy"][f.h0:f.h1], np.float64),
-                params=reference.to_host(f.params),
-                delta_b=np.asarray(st.delta_b, np.float64),
-                dist=np.asarray(st.dist_cache, np.float64),
-                seen=np.asarray(st.seen),
-                fresh=np.asarray(st.seen) & ~pending, mid=mid))
-        return out
-
 
 def follow(cell: catalog.Cell, job_seed: int, data: traffic.Traffic,
-           records: List[compare.Record], dtype=None):
+           followed: List[Followed], dtype=None, p0=None):
     """The reference over the followed calls; returns (refs, θ₀, the
-    reference's job, the clients' weights)."""
+    reference's job, the clients' weights).  θ₀ is kept once, as float32
+    host leaves; ``p0`` gives it instead (the control's changes are
+    taken from the float32 reference's θ₀).  Each call donates the carry
+    to the next, and of a call's parameters only each leaf's change
+    norm comes to the host, one leaf at a time."""
     wl = cell.workload
     block = wl.get("reference_cohort_block")
     rj = reference.Job(
@@ -200,19 +218,21 @@ def follow(cell: catalog.Cell, job_seed: int, data: traffic.Traffic,
         dtype=dtype or jnp.float32,
         cohort_block=None if block is None else int(block))
     carry = reference.start(rj, job_seed, int(cell.config["num_classes"]))
-    p0 = reference.to_host(carry[0])
+    if p0 is None:
+        p0 = jax.tree_util.tree_map(lambda a: np.array(a, np.float32),
+                                    carry[0])
     w = data.sizes.astype(np.float64)
     weights = jnp.asarray(w / w.sum(), jnp.float32)
     refs = []
-    for rec in records:
-        rng0 = carry[2]
+    for f in followed:
+        rng0 = np.array(carry[2])
         carry, loss, ent, mid_db = reference.follow_call(
-            rj, data.x, data.y, data.mask, carry, rec.ids,
-            mid=[t for t, _, _ in rec.mid])
+            rj, data.x, data.y, data.mask, carry, f.ids,
+            mid=[t for t, _, _ in f.mid])
         refs.append(compare.RefCall(
-            loss=loss, ent=ent, params=reference.to_host(carry[0]),
+            loss=loss, ent=ent, norms=compare.change_norms(carry[0], p0),
             delta_b=np.asarray(carry[1], np.float64), rng0=rng0,
-            rng=carry[2], mid_delta_b=mid_db))
+            mid_delta_b=mid_db))
     return refs, p0, rj, weights
 
 
@@ -325,18 +345,17 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     device = device_entry(devs)
     gc.unfreeze()
 
-    records = job.records()
     wl = cell.workload
     k, r = int(wl["num_select"]), int(wl["job_rounds"])
-    data, job_seed = job.data, job.prog_seed
+    followed, data, job_seed = job.followed, job.data, job.prog_seed
     del job, probe                   # the program's state goes first
     gc.collect()
     r0 = time.perf_counter()
-    values = check(cell, job_seed, data, records)
+    values = check(cell, job_seed, data, followed)
     reference_s = time.perf_counter() - r0
     limits = {n: float(v) for n, v in wl["limits"].items()}
     ok = compare.verdict(values, limits)
-    followed = sum(len(x.ids) for x in records)
+    rounds = sum(len(f.ids) for f in followed)
 
     if trace:
         metrics = {m["name"]: {"value": layer["metrics"][m["name"]],
@@ -353,13 +372,13 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
             "setup_s": {"value": setup_s, "unit": "s"},
         }
         metrics = {m["name"]: e2e[m["name"]] for m in cell.end_to_end}
-    out = {"correct": bool(ok), "attempted": followed,
-           "failed": 0 if ok else followed, "metrics": metrics,
+    out = {"correct": bool(ok), "attempted": rounds,
+           "failed": 0 if ok else rounds, "metrics": metrics,
            "device": device}
     if trace:
         out["breakdown"] = layer["breakdown"]
     info = {"setup_s": setup_s, "compiles_in_window": compiles.count,
-            "rounds_followed": followed, "reference_s": reference_s}
+            "rounds_followed": rounds, "reference_s": reference_s}
     if trace:
         info.update(layer["info"])
     else:
@@ -373,43 +392,44 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
 
 
 def check(cell: catalog.Cell, job_seed: int, data: traffic.Traffic,
-          records: List[compare.Record]) -> Dict[str, float]:
+          followed: List[Followed]) -> Dict[str, float]:
     """The comparison's numbers: the reference over the followed calls
-    against what the program recorded."""
-    refs, p0, rj, weights = follow(cell, job_seed, data, records)
+    against what the program left in them."""
+    refs, p0, rj, weights = follow(cell, job_seed, data, followed)
+    records = [f.record(p0) for f in followed]
     return compare.numbers(
-        records, refs, p0, reference.reference_distance,
+        records, refs, reference.reference_distance,
         expected_selections(rj, weights, records, refs))
 
 
 def control_check(cell: catalog.Cell, job_seed: int,
                   data: traffic.Traffic,
-                  records: List[compare.Record]) -> Dict[str, float]:
+                  followed: List[Followed]) -> Dict[str, float]:
     """The same numbers for the control: the reference computed in
     bfloat16 (its Eq. 9 Gram too) put in the program's place, on the
     program's cohorts, against the float32 reference."""
-    refs, p0, rj, weights = follow(cell, job_seed, data, records)
-    low, _, lj, _ = follow(cell, job_seed, data, records,
-                           dtype=jnp.bfloat16)
+    refs, p0, rj, weights = follow(cell, job_seed, data, followed)
+    low, _, lj, _ = follow(cell, job_seed, data, followed,
+                           dtype=jnp.bfloat16, p0=p0)
     n = int(cell.workload["num_clients"])
     stand_in = []
-    for i, (c, rec) in enumerate(zip(low, records)):
-        ids = rec.ids.copy()   # the control's own choices, from its state
+    for i, (c, f) in enumerate(zip(low, followed)):
+        ids = f.ids.copy()     # the control's own choices, from its state
         if i:
             ids[0] = reference.select_at(lj, low[i - 1].delta_b,
-                                         records[i - 1].seen, weights,
+                                         followed[i - 1].seen, weights,
                                          c.rng0)
         mid = []
-        for t, _, seen in rec.mid:
+        for t, _, seen in f.mid:
             mid.append((t, c.mid_delta_b[t], seen))
             ids[t] = reference.select_at(lj, c.mid_delta_b[t], seen,
                                          weights, c.rng0, t)
         stand_in.append(compare.Record(
-            ids=ids, loss=c.loss, ent=c.ent, params=c.params,
+            ids=ids, loss=c.loss, ent=c.ent, norms=c.norms,
             delta_b=c.delta_b, dist=reference.control_distance(c.delta_b),
-            seen=rec.seen, fresh=np.ones(n, bool), mid=mid))
+            seen=f.seen, fresh=np.ones(n, bool), mid=mid))
     return compare.numbers(
-        stand_in, refs, p0, reference.reference_distance,
+        stand_in, refs, reference.reference_distance,
         expected_selections(rj, weights, stand_in, refs))
 
 

@@ -28,7 +28,7 @@ def followed(cell, seed: int, plant=None, selector_kw=None):
         plant(job)
     while job.following:
         job.call()
-    out = job.records(), job.prog_seed, job.data
+    out = job.followed, job.prog_seed, job.data
     del job
     gc.collect()
     return out
@@ -38,11 +38,11 @@ def readings(cell, seed: int, control: bool) -> dict:
     """The program's numbers for ``seed``, and the control's beside
     them when ``control``: one set-up serves both."""
     from benchlib import session
-    records, job_seed, data = followed(cell, seed)
-    out = {"program": session.check(cell, job_seed, data, records)}
+    calls, job_seed, data = followed(cell, seed)
+    out = {"program": session.check(cell, job_seed, data, calls)}
     if control:
         out["control"] = session.control_check(cell, job_seed, data,
-                                               records)
+                                               calls)
     return out
 
 
@@ -56,11 +56,11 @@ def fault_readings(cell, seed: int) -> dict:
         if name == "unchanged_state":     # reads 1 on `update` by design
             continue
         with fault() as plant:
-            records, job_seed, data = followed(cell, seed, plant)
-        out[name] = session.check(cell, job_seed, data, records)
-    records, job_seed, data = followed(cell, seed,
-                                       selector_kw={"gram_in_bf16": True})
-    out["gram_in_bf16"] = session.check(cell, job_seed, data, records)
+            calls, job_seed, data = followed(cell, seed, plant)
+        out[name] = session.check(cell, job_seed, data, calls)
+    calls, job_seed, data = followed(cell, seed,
+                                     selector_kw={"gram_in_bf16": True})
+    out["gram_in_bf16"] = session.check(cell, job_seed, data, calls)
     return out
 
 

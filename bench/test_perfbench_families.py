@@ -212,7 +212,8 @@ def _follow(cell, fam, data, x=None, **kw):
     carry, loss, _, _ = reference.follow_call(
         job, data.x if x is None else x, data.y, data.mask, carry,
         _cohorts(job))
-    return reference.to_host(carry[0]), np.asarray(carry[1]), loss
+    return (jax.tree_util.tree_map(lambda a: np.asarray(a, np.float64),
+                                   carry[0]), np.asarray(carry[1]), loss)
 
 
 @pytest.mark.parametrize("block", [1, 2])

@@ -2,7 +2,7 @@
 
     python chip_smoke.py
 
-One process, five phases, in this order; any failure raises and the
+One process, six phases, in this order; any failure raises and the
 script exits non-zero without printing a result:
 
   device   JAX must see a TPU (never falls back to the CPU).
@@ -12,6 +12,10 @@ script exits non-zero without printing a result:
            (``tpu_custom_call``) and its result must match the same
            call with ``use_pallas=False`` (the jnp oracle, run at
            "highest" matmul precision) within ``KERNEL_TOL``.
+  ward     Ward's clustering of the oracle's N=512 Eq. 9 matrix into
+           K clusters: the compiled program must hold the
+           ``ward_merge`` kernel, and its labels and repair count must
+           equal the XLA merge loop's exactly.
   sync     ``repro.fed.build`` + ``server.run()`` on the scanned round
            loop, selector hics then cs, at ``paper-cnn``'s published
            shape (``ExperimentSpec`` defaults: N=50, K=5, §4.1
@@ -155,6 +159,33 @@ def phase_kernels(dev, n: int = KERNEL_N, k: int = KERNEL_K,
                              + "\n  ".join(failures))
 
 
+def phase_ward(dev, n: int = KERNEL_N, k: int = KERNEL_K) -> None:
+    from repro.core.clustering import _agglomerate_device, _merge_loop
+    from repro.kernels import ops
+    x = 0.02 * jax.random.normal(jax.random.PRNGKey(0), (n, 10))
+    with jax.default_matmul_precision("highest"):
+        _, d = ops.hics_selection_step(x, TEMPERATURE, lam=LAM,
+                                       use_pallas=False)
+    run = jax.jit(lambda d: _agglomerate_device(d, k, precomputed=True))
+    t0 = time.perf_counter()
+    compiled = run.lower(d).compile()
+    t_compile = time.perf_counter() - t0
+    if "%ward_merge" not in compiled.as_text():
+        raise AssertionError("Ward's clustering did not compile to the "
+                             "ward_merge kernel")
+    labels, repairs, in_vmem = compiled(d)
+    want, want_repairs = jax.jit(_merge_loop, static_argnums=(1, 2))(
+        d, k, "ward")
+    uniq = jnp.unique(want, size=k)
+    want = jnp.searchsorted(uniq, want)
+    if not (bool(jnp.array_equal(labels, want)) and int(in_vmem) == 1
+            and int(repairs) == int(want_repairs)):
+        raise AssertionError("ward_merge differs from the XLA merge loop")
+    _say(dev.device_kind, f"kernel ward_merge N={n}: compile "
+         f"{t_compile:.2f}s, labels and {int(repairs)} repairs as the "
+         "XLA loop's")
+
+
 # ---------------------------------------------------------------------------
 # federated drivers
 # ---------------------------------------------------------------------------
@@ -281,6 +312,7 @@ def main() -> None:
     enable_compile_cache()
     dev = phase_device()
     phase_kernels(dev)
+    phase_ward(dev)
     server, spec, hist = phase_sync(dev, "hics")
     phase_sync(dev, "cs")
     phase_sweep(dev)

@@ -22,7 +22,9 @@ copies, and no (N, N) argmin.  Rows retired by a merge are parked at
 :func:`agglomerate_device` runs the same merge loop in float32 inside
 ``jit``: the same cache and pick-time check (a ``while_loop``), so the
 same merge order and the same Lance–Williams values, and O(N) work per
-merge with no pass over the (N, N) matrix.
+merge with no pass over the (N, N) matrix.  On a TPU, up to the N whose
+matrix fits VMEM, the whole loop is one Pallas kernel
+(``kernels/ward_merge.py``); elsewhere it is an XLA loop.
 """
 from __future__ import annotations
 
@@ -31,6 +33,8 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels.ward_merge import fits_vmem, lance_williams, ward_merge
 
 _LINKAGES = ("ward", "average", "complete", "single")
 
@@ -165,13 +169,13 @@ def agglomerate_device(dist: jnp.ndarray, num_clusters: int,
     also returns the winner's write stamp and size, so a merge runs
     about a dozen small ops plus five a repair.
 
-    A merge writes one row of the matrix, the merged cluster's, and no
-    column: a column write next to a row write makes the TPU compiler
-    keep the matrix in two layouts and copy all of it twice a merge.
-    A row is read as it stands instead: entry c of row k comes from
-    row k or from row c, whichever was written later (``stamp``), and
-    retired clusters (size 0) read +inf.  No op inside the merge loop
-    passes over the (N, N) matrix.
+    Where the matrix fits VMEM (``kernels.ward_merge.fits_vmem``: N up
+    to about 5,000) and the program is lowered for a TPU, the whole
+    loop runs as one Pallas kernel, ``ward_merge``, with the matrix
+    resident in VMEM.  Elsewhere (the CPU, larger N) it runs as an XLA
+    ``fori_loop`` (:func:`_merge_loop`).  Both make the same merges in
+    the same order with the same float32 values, so the labels do not
+    depend on the path.
 
     Because merges always absorb the higher index into the lower, each
     surviving representative r first appears in the label vector at
@@ -190,6 +194,40 @@ def agglomerate_device(dist: jnp.ndarray, num_clusters: int,
                                precomputed)[0]
 
 
+def _agglomerate_device(dist: jnp.ndarray, num_clusters: int,
+                        linkage: str = "ward", precomputed: bool = False
+                        ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """:func:`agglomerate_device` plus the number of stale-row repairs
+    the pick loop made over all merges and whether the merges ran in
+    the VMEM kernel (1) or the XLA loop (0), both int32 scalars."""
+    if linkage not in _LINKAGES:
+        raise ValueError(f"linkage must be one of {_LINKAGES}")
+    n = dist.shape[0]
+    num_clusters = max(1, min(int(num_clusters), n))
+    d = jnp.asarray(dist, jnp.float32)
+    if not precomputed:
+        d = 0.5 * (d + d.T)
+    reps, repairs, in_vmem = _merge(d, num_clusters, linkage)
+    uniq = jnp.unique(reps, size=num_clusters)
+    return (jnp.searchsorted(uniq, reps).astype(jnp.int32), repairs,
+            in_vmem)
+
+
+def _merge(d: jnp.ndarray, num_clusters: int, linkage: str):
+    """(representatives, repairs, in_vmem): the merge loop on the path
+    the platform and N allow."""
+    def loop(d):
+        return (*_merge_loop(d, num_clusters, linkage), jnp.int32(0))
+
+    if not fits_vmem(d.shape[0]):
+        return loop(d)
+
+    def kernel(d):
+        return (*ward_merge(d, num_clusters, linkage), jnp.int32(1))
+
+    return jax.lax.platform_dependent(d, tpu=kernel, default=loop)
+
+
 class _Pick(NamedTuple):
     """One pick of the merge loop: row ``p`` with the least cached
     minimum ``low``, its true minimum at its first column ``q``, what
@@ -206,18 +244,20 @@ class _Pick(NamedTuple):
     repairs: jnp.ndarray
 
 
-def _agglomerate_device(dist: jnp.ndarray, num_clusters: int,
-                        linkage: str = "ward", precomputed: bool = False
-                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """:func:`agglomerate_device` plus the number of stale-row repairs
-    the pick loop made over all merges (an int32 scalar)."""
-    if linkage not in _LINKAGES:
-        raise ValueError(f"linkage must be one of {_LINKAGES}")
-    n = dist.shape[0]
-    num_clusters = max(1, min(int(num_clusters), n))
-    d = jnp.asarray(dist, jnp.float32)
-    if not precomputed:
-        d = 0.5 * (d + d.T)
+def _merge_loop(d: jnp.ndarray, num_clusters: int, linkage: str
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The merge loop as an XLA ``fori_loop`` on a float32 matrix:
+    (each item's representative, the lowest index of its cluster;
+    repairs).
+
+    A merge writes one row of the matrix, the merged cluster's, and no
+    column: a column write next to a row write makes the TPU compiler
+    keep the matrix in two layouts and copy all of it twice a merge.
+    A row is read as it stands instead: entry c of row k comes from
+    row k or from row c, whichever was written later (``stamp``), and
+    retired clusters (size 0) read +inf.  No op inside the merge loop
+    passes over the (N, N) matrix."""
+    n = d.shape[0]
     if linkage == "ward":
         d = d * d
     d = jnp.where(jnp.eye(n, dtype=bool), jnp.inf, d)
@@ -270,15 +310,7 @@ def _agglomerate_device(dist: jnp.ndarray, num_clusters: int,
         i, j = jnp.where(swap, q, p), jnp.where(swap, p, q)
         di, dj = jnp.where(swap, dq, dp), jnp.where(swap, dp, dq)
         ni, nj = jnp.where(swap, n_q, n_p), jnp.where(swap, n_p, n_q)
-        if linkage == "ward":
-            new = ((ni + sizes) * di + (nj + sizes) * dj
-                   - sizes * dij) / (ni + nj + sizes)
-        elif linkage == "average":
-            new = (ni * di + nj * dj) / (ni + nj)
-        elif linkage == "complete":
-            new = jnp.maximum(di, dj)
-        else:  # single
-            new = jnp.minimum(di, dj)
+        new = lance_williams(linkage, di, dj, dij, ni, nj, sizes)
         is_i, is_j = idx == i, idx == j
         new = jnp.where(is_i | is_j, jnp.inf, new)
         d = jax.lax.dynamic_update_slice(d, new[None, :], (i, 0))
@@ -294,8 +326,7 @@ def _agglomerate_device(dist: jnp.ndarray, num_clusters: int,
         0, n - num_clusters, body,
         (d, jnp.zeros(n, jnp.int32), jnp.min(d, axis=1),
          jnp.ones(n, jnp.float32), idx, jnp.zeros((), jnp.int32)))
-    reps = jnp.unique(labels, size=num_clusters)
-    return jnp.searchsorted(reps, labels).astype(jnp.int32), repairs
+    return labels, repairs
 
 
 def cluster_means_device(values: jnp.ndarray, labels: jnp.ndarray,

@@ -96,9 +96,9 @@ class FunctionalSelector(NamedTuple):
     #: optional (state) -> (N,) Ĥ, for history recording inside the scan
     entropies: Optional[Callable[[SelectorState], jnp.ndarray]] = None
     #: optional (state) -> {"cluster_sizes": (M,), "cluster_ent_spread":
-    #: (), "cluster_repairs": ()} — clustering-health observables for
-    #: the telemetry ``selection`` group.  Pure/jit-compatible like
-    #: ``entropies``.
+    #: (), "cluster_repairs": (), "cluster_in_vmem": ()} — clustering-
+    #: health observables for the telemetry ``selection`` group.
+    #: Pure/jit-compatible like ``entropies``.
     diagnostics: Optional[Callable[[SelectorState], dict]] = None
     #: optional observed-full-update-width -> stored-feature-width map.
     #: Selectors that down-project |θ|-sized updates (cs/divfl with

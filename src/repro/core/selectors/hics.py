@@ -151,15 +151,17 @@ def hics_functional(num_clients: int, num_select: int, total_rounds: int,
         # clustering-health observables for the telemetry ``selection``
         # group: re-cluster the cached Eq. 9 distance (incremental path
         # only — from-scratch mode has no resident distance to read)
-        # and report cluster sizes, the within-cluster Ĥ RMS spread and
-        # how many stale rows the merge loop's min cache repaired.
+        # and report cluster sizes, the within-cluster Ĥ RMS spread, how
+        # many stale rows the merge loop's min cache repaired and whether
+        # the merges ran in the VMEM kernel.
         ent = state.row_stats[:, 1]
-        labels, repairs = _agglomerate_device(
+        labels, repairs, in_vmem = _agglomerate_device(
             state.dist_cache, m, linkage=linkage, precomputed=True)
         means = cluster_means_device(ent, labels, m)
         return {
             "cluster_sizes": jnp.bincount(labels, length=m),
             "cluster_repairs": repairs,
+            "cluster_in_vmem": in_vmem,
             "cluster_ent_spread": jnp.sqrt(
                 jnp.mean(jnp.square(ent - means[labels]))),
         }

@@ -15,9 +15,13 @@
                      arccos/λ|ΔĤ| tail in XLA, plus the end-to-end
                      fused selection step
   decode_attention — GQA flash-decode for the serving hot loop
+  ward_merge       — Ward's whole agglomerative merge loop in one launch,
+                     the (N, N) distance resident in VMEM; called by
+                     ``core.clustering``, whose XLA merge loop is its
+                     oracle and its fallback (CPU, N past VMEM)
 
-Each kernel has a pure-jnp oracle in ref.py; ops.py is the dispatching
-public API (TPU -> compiled Pallas, CPU -> interpret/oracle).
+Each other kernel has a pure-jnp oracle in ref.py; ops.py is the
+dispatching public API (TPU -> compiled Pallas, CPU -> interpret/oracle).
 
 All entry points are jit/scan-compatible: ``hics_selection_step`` is
 the device half of the functional selector protocol

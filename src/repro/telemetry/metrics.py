@@ -31,8 +31,10 @@ Groups:
               correlation (the Eq. 9 estimation-quality observable;
               needs ``ctx.true_entropy``), distance-cache staleness
               fill, and — when the selector exposes ``diagnostics`` —
-              cluster sizes, within-cluster Ĥ spread and the stale-row
-              repairs of one clustering of the cached distance.
+              cluster sizes, within-cluster Ĥ spread, the stale-row
+              repairs of one clustering of the cached distance and
+              whether its merges ran in the VMEM kernel (1) or the
+              XLA loop (0).
   training  — per-round train loss, mean ‖Δb‖ row norm, global update
               norm ‖θ^{t+1} − θ^t‖, lr scale.
   fairness  — cumulative times-selected histogram, participation rate
@@ -221,10 +223,13 @@ def make_metrics(spec: MetricsSpec, fn=None, num_clients: int = 0,
                 diag["cluster_ent_spread"])
             out["selection/cluster_repairs"] = jnp.asarray(
                 diag["cluster_repairs"], jnp.int32)
+            out["selection/cluster_in_vmem"] = jnp.asarray(
+                diag["cluster_in_vmem"], jnp.int32)
         else:
             out["selection/cluster_sizes"] = _zi()
             out["selection/cluster_ent_spread"] = _zf()
             out["selection/cluster_repairs"] = _zi()
+            out["selection/cluster_in_vmem"] = _zi()
 
         # -- training -----------------------------------------------------
         out["training/loss"] = (

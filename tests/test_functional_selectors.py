@@ -12,7 +12,9 @@ from repro.core import (SELECTORS, Observations, agglomerate,
                         cluster_means_device, hierarchical_sample_device,
                         make_functional, make_selector,
                         weighted_sample_device)
-from repro.core.clustering import _agglomerate_device
+from repro.core import clustering
+from repro.core.clustering import _agglomerate_device, _merge_loop
+from repro.kernels.ward_merge import ward_merge
 
 
 def _drive_functional(name, n, k, t_max, c, seed, db, full, losses):
@@ -210,50 +212,81 @@ def _eq9_like(rng, n, c=10):
     return np.arccos(np.clip(p @ p.T, -1.0, 1.0)).astype(np.float32)
 
 
+def _force_vmem(monkeypatch):
+    """Send ``_agglomerate_device``'s merges to the VMEM kernel, run in
+    interpret mode; on the CPU they would take the XLA loop."""
+    monkeypatch.setattr(clustering, "_merge", lambda d, m, linkage: (
+        *ward_merge(d, m, linkage, interpret=True), jnp.int32(1)))
+
+
 _MERGE_CASES = (
-    [pytest.param(lk, 18, "points", id=lk) for lk in LINKAGES]
-    + [pytest.param(lk, n, kind, id=f"{lk}-{n}-{kind}")
+    [pytest.param(lk, 18, "points", "loop", id=lk) for lk in LINKAGES]
+    + [pytest.param(lk, n, kind, "loop", id=f"{lk}-{n}-{kind}")
        for lk in LINKAGES for n, kind in ((18, "ties"), (257, "points"),
-                                          (257, "ties"))])
+                                          (257, "ties"))]
+    + [pytest.param(lk, n, kind, "vmem", id=f"{lk}-{n}-{kind}-vmem")
+       for lk in LINKAGES for n, kind in ((18, "points"), (18, "ties"),
+                                          (50, "points"), (130, "ties"),
+                                          (257, "points"), (257, "ties"))])
 
 
-@pytest.mark.parametrize("linkage,n,kind", _MERGE_CASES)
-def test_agglomerate_device_matches_numpy(linkage, n, kind, rng):
+@pytest.mark.parametrize("linkage,n,kind,path", _MERGE_CASES)
+def test_agglomerate_device_matches_numpy(linkage, n, kind, path, rng,
+                                          monkeypatch):
     """Bit-identical labels against the numpy twin and against the
-    whole-matrix argmin oracle: the same merge order, ties included."""
+    whole-matrix argmin oracle: the same merge order, ties included.
+    The VMEM kernel's cases also match the XLA loop's representatives
+    and repair count, at N off the 8 × 128 tiling."""
     d = _distances(rng, n, kind)
-    for m in (1, 2, 4, 9, n):
+    if path == "vmem":
+        _force_vmem(monkeypatch)
+    for m in (1, 2, 4, 9, n - 1, n):
         a = agglomerate(d, m, linkage=linkage)
-        b = np.asarray(agglomerate_device(jnp.asarray(d), m,
-                                          linkage=linkage))
-        np.testing.assert_array_equal(a, b)
+        b, repairs, in_vmem = _agglomerate_device(jnp.asarray(d), m,
+                                                  linkage=linkage)
+        assert int(in_vmem) == (path == "vmem")
+        np.testing.assert_array_equal(a, np.asarray(b))
         np.testing.assert_array_equal(_agglomerate_flat(d, m, linkage), b)
+        if path == "vmem":
+            sym = jnp.asarray(0.5 * (d + d.T), jnp.float32)
+            reps, loop_repairs = _merge_loop(sym, m, linkage)
+            k_reps, k_repairs = ward_merge(sym, m, linkage, interpret=True)
+            np.testing.assert_array_equal(np.asarray(k_reps),
+                                          np.asarray(reps))
+            assert int(repairs) == int(k_repairs) == int(loop_repairs)
 
 
-def test_agglomerate_device_in_scan_and_vmap(rng):
+def test_agglomerate_device_in_scan_and_vmap(rng, monkeypatch):
     """Under ``jit`` inside ``lax.scan`` and under ``vmap`` over three
-    matrices (the repair loop then runs until the slowest is done) the
-    labels and repair counts equal the one-matrix calls."""
+    matrices (the XLA loop's repair loop then runs until the slowest is
+    done; the kernel gains a grid axis and clusters each in turn) the
+    labels and repair counts of both paths equal the one-matrix calls
+    of the XLA loop."""
     ds = jnp.asarray(np.stack([_eq9_like(rng, 40) for _ in range(2)]
                               + [_distances(rng, 40, "ties")]),
                      jnp.float32)
     m = 5
     one = [_agglomerate_device(d, m) for d in ds]
 
-    @jax.jit
-    def scanned(ds):
-        return jax.lax.scan(
-            lambda c, d: (c, _agglomerate_device(d, m)), 0, ds)[1]
+    for path in ("loop", "vmem"):
+        if path == "vmem":
+            _force_vmem(monkeypatch)
 
-    for labels, repairs in (scanned(ds),
-                            jax.vmap(lambda d: _agglomerate_device(d, m))(
-                                ds)):
-        for s, (lab, rep) in enumerate(one):
-            np.testing.assert_array_equal(np.asarray(labels[s]),
-                                          np.asarray(lab))
-            np.testing.assert_array_equal(
-                np.asarray(labels[s]), _agglomerate_flat(ds[s], m))
-            assert int(repairs[s]) == int(rep)
+        @jax.jit
+        def scanned(ds):
+            return jax.lax.scan(
+                lambda c, d: (c, _agglomerate_device(d, m)), 0, ds)[1]
+
+        for labels, repairs, in_vmem in (
+                scanned(ds),
+                jax.vmap(lambda d: _agglomerate_device(d, m))(ds)):
+            assert np.all(np.asarray(in_vmem) == (path == "vmem"))
+            for s, (lab, rep, _) in enumerate(one):
+                np.testing.assert_array_equal(np.asarray(labels[s]),
+                                              np.asarray(lab))
+                np.testing.assert_array_equal(
+                    np.asarray(labels[s]), _agglomerate_flat(ds[s], m))
+                assert int(repairs[s]) == int(rep)
 
 
 def test_cluster_means_device_matches_numpy(rng):

@@ -185,6 +185,38 @@ def test_selection_cluster_repairs_counter():
     assert off["selection/cluster_repairs"].shape == (0,)
 
 
+@pytest.mark.parametrize("path", ["loop", "vmem"])
+def test_selection_cluster_in_vmem_counter(path, monkeypatch):
+    """``selection/cluster_in_vmem`` reads 0 where the clustering ran as
+    the XLA loop (the CPU) and 1 where it ran in the VMEM kernel
+    (forced here, in interpret mode); ``cluster_repairs`` is the same
+    on both."""
+    from repro.core import clustering, make_functional
+    from repro.kernels.ward_merge import ward_merge
+    if path == "vmem":
+        monkeypatch.setattr(clustering, "_merge", lambda d, m, lk: (
+            *ward_merge(d, m, lk, interpret=True), jax.numpy.int32(1)))
+    rng = np.random.default_rng(3)
+    pts = rng.normal(size=(40, 2))
+    d = np.linalg.norm(pts[:, None] - pts[None], axis=-1)
+    fn = make_functional("hics", num_clients=40, num_select=4,
+                         total_rounds=4)
+    state = fn.init(jax.random.PRNGKey(0))
+    state = state._replace(dist_cache=jax.numpy.asarray(d,
+                                                        jax.numpy.float32))
+    m = make_metrics(MetricsSpec(("selection",)), fn=fn, num_clients=40,
+                     num_select=4)
+    _, tel = m.step(m.init(), TelemetryCtx(ids=np.arange(4), state=state))
+    flag = tel["selection/cluster_in_vmem"]
+    assert flag.shape == () and np.issubdtype(flag.dtype, np.integer)
+    assert int(flag) == (path == "vmem")
+    k = int(fn.diagnostics(state)["cluster_sizes"].shape[0])
+    assert int(tel["selection/cluster_repairs"]) == _ward_repairs(d, k)
+    off_m = make_metrics(MetricsSpec())
+    _, off = off_m.step(off_m.init(), TelemetryCtx())
+    assert off["selection/cluster_in_vmem"].shape == (0,)
+
+
 # ---------------------------------------------------------------------------
 # invariance: telemetry never perturbs the run (the core guarantee)
 # ---------------------------------------------------------------------------

@@ -5,8 +5,9 @@ compiler refuses: a primitive Mosaic cannot lower, a misaligned tile, a
 VMEM overrun.  These tests compile each kernel with ``interpret=False``
 for one chip of a described ``v5e:2x2`` topology — no chip is attached
 and nothing runs — and check that the compiled program holds the kernel
-(``tpu_custom_call``); the Ward merge loop, that it updates its
-distance matrix in place with no whole-matrix copy.  The topology is
+(``tpu_custom_call``); Ward's clustering, that it runs as the one
+``ward_merge`` kernel, and that the XLA merge loop it falls back to
+updates its distance matrix in place with no whole-matrix copy.  The topology is
 described inside a fixture, so a worker that cannot describe one skips
 these tests, and no other module or worker loads the TPU compiler.
 """
@@ -136,13 +137,34 @@ def _loop_ops(hlo: str, shape: str) -> set:
 
 def test_ward_merge_loop_is_copy_free_for_v5e(one_chip):
     """At the cross-device cell's N, the only op on the (N, N) matrix
-    inside the merge loop is the in-place row update: no copy, no
-    relayout, no whole-matrix reduction."""
-    from repro.core.clustering import agglomerate_device
+    inside the XLA merge loop (the fallback past the VMEM budget) is
+    the in-place row update: no copy, no relayout, no whole-matrix
+    reduction."""
+    from repro.core.clustering import _merge_loop
     n = 2000
     d = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=one_chip)
-    hlo = jax.jit(lambda d: agglomerate_device(d, 10, precomputed=True)
+    hlo = jax.jit(lambda d: _merge_loop(d, 10, "ward")
                   ).lower(d).compile().as_text()
     kinds = _loop_ops(hlo, f"f32[{n},{n}]")
     assert kinds - {"parameter", "get-tuple-element", "tuple"} == {
         "dynamic-update-slice"}
+
+
+@pytest.mark.parametrize("batch", [(), (SEEDS,)], ids=["one", "vmapped"])
+@pytest.mark.parametrize("n", [2000, 4000])
+def test_agglomerate_device_runs_in_vmem_for_v5e(one_chip, n, batch):
+    """At the cross-device cell's N and at twice it, one matrix or the
+    sweep's vmapped batch, clustering for a TPU compiles to the one
+    ``ward_merge`` kernel, with no (N, N) op inside any loop of the
+    program."""
+    from repro.core.clustering import _agglomerate_device
+    run = lambda d: _agglomerate_device(d, 10, precomputed=True)
+    for _ in batch:
+        run = jax.vmap(run)
+    d = jax.ShapeDtypeStruct(batch + (n, n), jnp.float32,
+                             sharding=one_chip)
+    hlo = jax.jit(run).lower(d).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 1 and "%ward_merge" in hlo
+    shape = "f32[%s]" % ",".join(map(str, batch + (n, n)))
+    assert _loop_ops(hlo, shape) <= {"parameter", "get-tuple-element",
+                                     "tuple"}
